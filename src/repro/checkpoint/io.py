@@ -160,11 +160,17 @@ def _key_data(key) -> Optional[np.ndarray]:
 
 
 def save_server_checkpoint(dirpath: str, server, round_idx: int, *,
-                           server_opt_state=None, rng_key=None) -> None:
+                           server_opt_state=None, rng_key=None,
+                           backbone: bool = True) -> None:
     """Persist a server snapshot: backbone, global adapters, CommLog, and —
-    the pieces v1 silently dropped — the ServerOpt moments and round RNG."""
+    the pieces v1 silently dropped — the ServerOpt moments and round RNG.
+
+    ``backbone=False`` skips the frozen backbone (12.6 GiB at llava-1.5-7b
+    width); :func:`load_server_checkpoint` then keeps the caller's, which
+    must be rebuilt from the same seed."""
     os.makedirs(dirpath, exist_ok=True)
-    save_pytree(os.path.join(dirpath, "backbone.npz"), server.backbone)
+    if backbone:
+        save_pytree(os.path.join(dirpath, "backbone.npz"), server.backbone)
     save_pytree(os.path.join(dirpath, "global_adapters.npz"),
                 server.global_adapters)
     if server_opt_state is not None:
@@ -178,6 +184,7 @@ def save_server_checkpoint(dirpath: str, server, round_idx: int, *,
         "round_idx": round_idx,
         "cfg_name": server.cfg.name,
         "server_round_idx": server.round_idx,
+        "has_backbone": backbone,
         "has_server_opt_state": server_opt_state is not None,
         "has_rng_key": kd is not None,
         "comm_rounds": [r.to_dict() for r in server.comm.rounds],
@@ -215,8 +222,9 @@ def load_server_checkpoint(dirpath: str, server, *, server_opt_state=None):
             f"code reads v{SERVER_CHECKPOINT_VERSION}; older checkpoints "
             "lack the ServerOpt moments / round RNG and cannot be resumed "
             "faithfully — re-save with the current code")
-    backbone = load_pytree(os.path.join(dirpath, "backbone.npz"),
-                           server.backbone)
+    backbone = server.backbone
+    if meta.get("has_backbone", True):
+        backbone = load_pytree(os.path.join(dirpath, "backbone.npz"), backbone)
     adapters = load_pytree(os.path.join(dirpath, "global_adapters.npz"),
                            server.global_adapters)
     comm = CommLog(rounds=[RoundTraffic.from_dict(d)

@@ -48,9 +48,7 @@ def nano_adapter_apply(params, x, *, rank: int, alpha: float, use_pallas: bool =
     if use_pallas:
         from repro.kernels.lora import ops as lora_ops
 
-        return lora_ops.lora_residual(
-            x, params["down"], params["up"], scale=scale, interpret=True
-        )
+        return lora_ops.lora_residual(x, params["down"], params["up"], scale=scale)
     # compute in the activation dtype (bf16 on the mesh): fp32 master weights
     # are cast at use so no fp32 activation ever crosses a collective
     # (EXPERIMENTS.md §Perf glm4/train iteration 3); grads still flow to the

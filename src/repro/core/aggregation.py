@@ -60,7 +60,7 @@ def fisher_merge(
         from repro.kernels.fisher_merge import ops as fm_ops
 
         return jax.tree.map(
-            lambda t, f: fm_ops.fisher_merge(t, f, w, eps=eps, interpret=True), ts, fs
+            lambda t, f: fm_ops.fisher_merge(t, f, w, eps=eps), ts, fs
         )
 
     def merge(t, f):

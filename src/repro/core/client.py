@@ -415,14 +415,12 @@ def make_many_update(cfg, strategy, hp: HyperParams, *, downloads: bool,
     vm = jax.vmap(one_client,
                   in_axes=(None, None, 0, 0, 0, 0, batch_ax, batch_ax, batch_ax))
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
-
         rep, shd = P(), P(*(a for a in mesh.axis_names))
         bspec = rep if shared_batches else shd
-        vm = shard_map(
+        vm = jax.shard_map(
             vm, mesh=mesh,
             in_specs=(rep, rep, shd, shd, shd, shd, bspec, bspec, bspec),
-            out_specs=shd, check_rep=False)
+            out_specs=shd, check_vma=False)
     return jax.jit(vm)
 
 

@@ -327,7 +327,11 @@ def run_federated(
 
     k_server, k_clients = jax.random.split(key)
     if server is None:
-        server = server_lib.init_server(k_server, cfg)
+        # under a mesh the backbone is born replicated on it, so placing it
+        # below moves nothing and no device ever holds two copies
+        server = server_lib.init_server(
+            k_server, cfg,
+            sharding=NamedSharding(mesh, PartitionSpec()) if mesh is not None else None)
     cids = sorted(train_data)
     index_of = {cid: i for i, cid in enumerate(cids)}
     ckeys = jax.random.split(k_clients, len(cids))

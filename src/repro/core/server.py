@@ -27,9 +27,10 @@ class ServerState:
     round_idx: int = 0
 
 
-def init_server(key, cfg) -> ServerState:
+def init_server(key, cfg, *, sharding=None) -> ServerState:
+    """Fresh server; ``sharding`` places the backbone where it will live."""
     kb, ka = jax.random.split(key)
-    backbone = model_lib.init_backbone(kb, cfg)
+    backbone = model_lib.init_backbone(kb, cfg, sharding)
     global_adapters = adapters_lib.init_nanoedge(ka, cfg)
     return ServerState(cfg=cfg, backbone=backbone, global_adapters=global_adapters)
 

@@ -1,4 +1,7 @@
-"""Pallas TPU kernels for the compute hot-spots (validated interpret=True on CPU).
+"""Pallas TPU kernels for the compute hot-spots.
+
+They compile on a TPU and run in the Pallas interpreter elsewhere; the one
+place that decides is ``repro.kernels.platform.interpret_mode``.
 
 Each kernel ships three files (per the repo convention):
     <name>.py  — pl.pallas_call + explicit BlockSpec VMEM tiling

@@ -8,16 +8,19 @@ Two forms of paper Eq. 1:
     ``repro.strategies`` builds FedNano's ``agg_stream_*`` hooks on it.
 
 ``block_n=None`` consults the tuning table (numerics-free: element blocks
-are independent).
+are independent). ``interpret=None`` follows the platform
+(``repro.kernels.platform``).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 
 from repro.kernels import tuning
 from repro.kernels.fisher_merge.fisher_merge import fisher_fold_2d, fisher_merge_2d
+from repro.kernels.platform import interpret_mode
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_n", "interpret"))
@@ -31,7 +34,7 @@ def _fisher_merge_jit(theta, fisher, weights, *, eps, block_n, interpret):
 
 
 def fisher_merge(theta, fisher, weights, *, eps: float = 1e-8,
-                 block_n: int = None, interpret: bool = False):
+                 block_n: int = None, interpret: Optional[bool] = None):
     """theta/fisher (K, ...) stacked client leaves; weights (K,).
 
     Returns the merged leaf of shape (...). ``block_n=None`` → tuning table.
@@ -42,7 +45,7 @@ def fisher_merge(theta, fisher, weights, *, eps: float = 1e-8,
             n *= int(s)
         block_n = tuning.fisher_block_n(theta.shape[0], n)
     return _fisher_merge_jit(theta, fisher, weights, eps=eps, block_n=block_n,
-                             interpret=interpret)
+                             interpret=interpret_mode(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -55,7 +58,7 @@ def _fisher_fold_jit(num, den, theta, fisher, w, *, block_n, interpret):
 
 
 def fisher_fold(num, den, theta, fisher, w, *, block_n: int = None,
-                interpret: bool = False):
+                interpret: Optional[bool] = None):
     """Streaming fold of one client leaf: returns (num + w·F·θ, den + w·F).
 
     num/den are float32 running sums shaped like the leaf; ``w`` is a scalar
@@ -67,4 +70,4 @@ def fisher_fold(num, den, theta, fisher, w, *, block_n: int = None,
             n *= int(s)
         block_n = tuning.fisher_block_n(1, n)
     return _fisher_fold_jit(num, den, theta, fisher, w, block_n=block_n,
-                            interpret=interpret)
+                            interpret=interpret_mode(interpret))

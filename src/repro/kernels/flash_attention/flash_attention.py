@@ -13,6 +13,11 @@ TPU-native online-softmax attention (the SDPA replacement, DESIGN.md §3):
     grok-style tanh softcap optionally applied pre-mask.
   * block sizes default to (128, 512) — MXU-aligned (multiples of 8×128
     lanes) and small enough that q, k, v, acc tiles fit VMEM at head_dim 256.
+  * head-major layout inside the kernel: q/k/v are (B, H, S, D) with
+    (1, 1, block, D) blocks and the logsumexp is (B, H, S, 1), so the last
+    two dims of every block are (block, D) or (block, 1) — the TPU's tiling
+    rule (divisible by (8, 128) or equal to the array dims). The public
+    wrapper keeps the model's (B, S, H, D) layout and transposes.
 
 Numerics: all softmax state in fp32 scratch regardless of input dtype.
 """
@@ -40,11 +45,13 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)   # (bq, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)   # (bk, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)   # (bk, D)
+    q = q_ref[0, 0].astype(jnp.float32)          # (bq, D)
+    k = k_ref[0, 0].astype(jnp.float32)          # (bk, D)
+    v = v_ref[0, 0].astype(jnp.float32)          # (bk, D)
 
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (bq, bk)
+    # q·kᵀ contracting the head dim of both (no explicit transpose)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
     if softcap and softcap > 0.0:
         s = jnp.tanh(s / softcap) * softcap
 
@@ -78,10 +85,10 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(kb == n_k - 1)
     def _finish():
         denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
         # per-row logsumexp (flash residual for the backward pass); rows
         # that never saw an unmasked key keep m == NEG_INF as the marker
-        lse_ref[0, :, 0] = (m_scr[...] + jnp.log(denom))[:, 0]
+        lse_ref[0, 0] = m_scr[...] + jnp.log(denom)
 
 
 def flash_attention(
@@ -97,7 +104,7 @@ def flash_attention(
     """q (B, Sq, H, D); k, v (B, Sk, Hkv, D), H % Hkv == 0. Returns (B, Sq, H, D).
 
     Query i has absolute position (Sk - Sq) + i (decode/prefill alignment).
-    With ``return_lse`` also returns the per-row logsumexp (B, Sq, H) — the
+    With ``return_lse`` also returns the per-row logsumexp (B, H, Sq) — the
     flash residual the custom VJP in ``ops.py`` rebuilds probabilities from.
     """
     B, Sq, H, D = q.shape
@@ -109,12 +116,11 @@ def flash_attention(
     bk = min(block_k, Sk)
     pad_q = (-Sq) % bq
     pad_k = (-Sk) % bk
-    if pad_q:
-        q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
-    if pad_k:
-        k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
-    Sqp, Skp = q.shape[1], k.shape[1]
+    # head-major (B, H, S, D), sequence padded to whole blocks
+    q = jnp.pad(jnp.swapaxes(q, 1, 2), ((0, 0), (0, 0), (0, pad_q), (0, 0)))
+    k = jnp.pad(jnp.swapaxes(k, 1, 2), ((0, 0), (0, 0), (0, pad_k), (0, 0)))
+    v = jnp.pad(jnp.swapaxes(v, 1, 2), ((0, 0), (0, 0), (0, pad_k), (0, 0)))
+    Sqp, Skp = q.shape[2], k.shape[2]
     n_q, n_k = Sqp // bq, Skp // bk
     q_offset = Sk - Sq
 
@@ -133,17 +139,17 @@ def flash_attention(
         ),
         grid=(B, H, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, i, j, g=group: (b, j, h // g, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, i, j, g=group: (b, j, h // g, 0)),
+            pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j, g=group: (b, h // g, j, 0)),
+            pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j, g=group: (b, h // g, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, h, i, j: (b, i, h)),
+            pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Sqp, H, D), q.dtype),
-            jax.ShapeDtypeStruct((B, Sqp, H), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sqp, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Sqp, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
@@ -153,6 +159,6 @@ def flash_attention(
         interpret=interpret,
     )(q, k, v)
     out, lse = out
-    if pad_q:
-        out, lse = out[:, :Sq], lse[:, :Sq]
+    out = jnp.swapaxes(out[:, :, :Sq], 1, 2)
+    lse = lse[:, :, :Sq, 0]
     return (out, lse) if return_lse else out

@@ -17,6 +17,7 @@ real differential test of both the kernel's LSE and the backward math.
 
 Block sizes: ``block_q=None`` / ``block_k=None`` consult the tuning table
 (``repro.kernels.tuning``); explicit values pass through untouched.
+``interpret=None`` follows the platform (``repro.kernels.platform``).
 """
 from __future__ import annotations
 
@@ -28,6 +29,7 @@ import jax.numpy as jnp
 
 from repro.kernels import tuning
 from repro.kernels.flash_attention.flash_attention import NEG_INF, flash_attention as _fa
+from repro.kernels.platform import interpret_mode
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
@@ -74,10 +76,10 @@ def _fa_bwd(causal, window, softcap, block_q, block_k, interpret, res, g):
     if window is not None:
         mask = mask & (qpos[:, None] - kpos[None, :] < window)
 
-    # p from the kernel's saved LSE; fully-masked rows carry lse ~ NEG_INF
-    lse_h = jnp.moveaxis(lse, 1, 2)                           # (B, H, Sq)
-    live = (lse_h > NEG_INF / 2)[..., None]                   # (B, H, Sq, 1)
-    p = jnp.where(mask[None, None] & live, jnp.exp(s - lse_h[..., None]), 0.0)
+    # p from the kernel's saved LSE (B, H, Sq); fully-masked rows carry
+    # lse ~ NEG_INF
+    live = (lse > NEG_INF / 2)[..., None]                     # (B, H, Sq, 1)
+    p = jnp.where(mask[None, None] & live, jnp.exp(s - lse[..., None]), 0.0)
 
     dv_h = jnp.einsum("bhqk,bqhd->bkhd", p, gf)
     dp = jnp.einsum("bqhd,bkhd->bhqk", gf, vf)
@@ -107,7 +109,7 @@ def _fa_jit(q, k, v, *, causal, window, softcap, block_q, block_k, interpret):
 
 def flash_attention(q, k, v, *, causal=True, window: Optional[int] = None,
                     softcap: float = 0.0, block_q: int = None,
-                    block_k: int = None, interpret: bool = False):
+                    block_k: int = None, interpret: Optional[bool] = None):
     """q (B, Sq, H, D); k, v (B, Sk, Hkv, D). Differentiable in (q, k, v).
 
     ``block_q``/``block_k`` = None → tuning table (clamped to the sequence
@@ -119,4 +121,5 @@ def flash_attention(q, k, v, *, causal=True, window: Optional[int] = None,
         block_q = bq if block_q is None else block_q
         block_k = bk if block_k is None else block_k
     return _fa_jit(q, k, v, causal=causal, window=window, softcap=softcap,
-                   block_q=block_q, block_k=block_k, interpret=interpret)
+                   block_q=block_q, block_k=block_k,
+                   interpret=interpret_mode(interpret))

@@ -15,16 +15,19 @@ pinned by the kernel harness (tests/kernel_harness.py).
 Block sizes: ``block_t=None`` consults the tuning table
 (``repro.kernels.tuning``); explicit values pass through untouched. Token
 blocking tiles independent rows, so every block size is bit-identical.
+``interpret=None`` follows the platform (``repro.kernels.platform``).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import tuning
 from repro.kernels.lora.lora import grouped_lora_residual_2d, lora_residual_2d
+from repro.kernels.platform import interpret_mode
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -66,7 +69,7 @@ def _lora_residual_jit(x, down, up, *, scale, block_t, interpret):
 
 
 def lora_residual(x, down, up, *, scale: float, block_t: int = None,
-                  interpret: bool = False):
+                  interpret: Optional[bool] = None):
     """y = x + scale·(x·down)·up for x of any leading shape (..., D).
 
     Differentiable in (x, down, up). ``block_t=None`` → tuning table.
@@ -77,7 +80,7 @@ def lora_residual(x, down, up, *, scale: float, block_t: int = None,
             t *= int(s)
         block_t = tuning.lora_block_t(t, x.shape[-1], down.shape[-1])
     return _lora_residual_jit(x, down, up, scale=scale, block_t=block_t,
-                              interpret=interpret)
+                              interpret=interpret_mode(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "block_t", "interpret"))
@@ -92,7 +95,7 @@ def _grouped_jit(x, down, up, idx, *, scale, block_t, interpret):
 
 
 def grouped_lora_residual(x, down, up, idx, *, scale: float, block_t: int = None,
-                          interpret: bool = False):
+                          interpret: Optional[bool] = None):
     """Multi-tenant LoRA: per-row adapter ids into a stacked bank.
 
     x (..., D); down (N, D, r); up (N, r, D); idx (...) int32 aligned with
@@ -105,4 +108,4 @@ def grouped_lora_residual(x, down, up, idx, *, scale: float, block_t: int = None
             t *= int(s)
         block_t = tuning.lora_block_t(t, x.shape[-1], down.shape[-1])
     return _grouped_jit(x, down, up, idx, scale=scale, block_t=block_t,
-                        interpret=interpret)
+                        interpret=interpret_mode(interpret))

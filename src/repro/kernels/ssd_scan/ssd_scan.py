@@ -1,6 +1,6 @@
 """Mamba2 SSD chunked scan — Pallas TPU kernel.
 
-TPU adaptation of the CUDA selective scan (DESIGN.md §3): the state-space
+TPU adaptation of the CUDA selective scan: the state-space
 duality lets each Q-length chunk be computed as two MXU matmuls (intra-chunk
 "attention" C·Bᵀ⊙decay and the state contraction) plus an O(1)-per-chunk
 recurrence. The kernel runs grid (B, H, n_chunks) with the chunk axis
@@ -23,49 +23,57 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, h_scr, *, chunk: int):
+def _kernel(x_ref, dt_ref, lc_ref, b_ref, c_ref, o_ref, h_scr, *, chunk: int):
     cb = pl.program_id(2)
 
     @pl.when(cb == 0)
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)     # (Q, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)      # (Q,)
-    A = a_ref[0].astype(jnp.float32)              # scalar (per head)
-    Bm = b_ref[0, :, :].astype(jnp.float32)       # (Q, N)
-    Cm = c_ref[0, :, :].astype(jnp.float32)       # (Q, N)
+    x = x_ref[0, 0].astype(jnp.float32)           # (Q, P)
+    dt = dt_ref[0, 0]                             # (Q, 1)
+    L = lc_ref[0, 0]                              # (Q, 1) chunk-local cumsum of dt·A
+    Bm = b_ref[0].astype(jnp.float32)             # (Q, N)
+    Cm = c_ref[0].astype(jnp.float32)             # (Q, N)
 
-    la = dt * A                                    # (Q,) log-decays (<= 0)
-    L = jnp.cumsum(la)                             # (Q,)
     # segment decay matrix: seg[i, j] = L_i - L_j for j <= i
-    li = L[:, None]
-    lj = L[None, :]
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    seg = jnp.where(jj <= ii, li - lj, -jnp.inf)
+    seg = jnp.where(jj <= ii, L - L.T, -jnp.inf)
 
-    xdt = x * dt[:, None]                          # (Q, P)
-    cb_mat = jnp.dot(Cm, Bm.T, preferred_element_type=jnp.float32)  # (Q, Q)
+    xdt = x * dt                                   # (Q, P)
+    cb_mat = jax.lax.dot_general(                  # C·Bᵀ (Q, Q)
+        Cm, Bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
     att = cb_mat * jnp.exp(seg)
     y_intra = jnp.dot(att, xdt, preferred_element_type=jnp.float32)  # (Q, P)
 
     # inter-chunk: y_i += exp(L_i) * C_i · h      (h: (N, P))
-    y_inter = jnp.exp(L)[:, None] * jnp.dot(
+    y_inter = jnp.exp(L) * jnp.dot(
         Cm, h_scr[...], preferred_element_type=jnp.float32
     )
 
-    o_ref[0, :, 0, :] = (y_intra + y_inter).astype(o_ref.dtype)
+    o_ref[0, 0] = (y_intra + y_inter).astype(o_ref.dtype)
 
     # state update: h' = exp(L_last) h + Σ_j exp(L_last - L_j) B_j ⊗ xdt_j
-    dec_last = jnp.exp(L[-1] - L)                  # (Q,)
-    h_scr[...] = jnp.exp(L[-1]) * h_scr[...] + jnp.dot(
-        (Bm * dec_last[:, None]).T, xdt, preferred_element_type=jnp.float32
-    )
+    L_last = L[chunk - 1:, :]                      # (1, 1)
+    dec_last = jnp.exp(L_last - L)                 # (Q, 1)
+    # (1, 1) -> (1, P) -> (N, P): Mosaic broadcasts lanes or sublanes, not
+    # both in one step
+    dec_chunk = jnp.exp(jnp.broadcast_to(L_last, (1, h_scr.shape[1])))
+    h_scr[...] = dec_chunk * h_scr[...] + jax.lax.dot_general(
+        Bm * dec_last, xdt, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
 
 def ssd_chunked_pallas(x, dt, A, B, C, *, chunk: int = 256, interpret: bool = False):
-    """x (Bt, S, H, P); dt (Bt, S, H); A (H,); B, C (Bt, S, N) -> y like x."""
+    """x (Bt, S, H, P); dt (Bt, S, H); A (H,); B, C (Bt, S, N) -> y like x.
+
+    The kernel works head-major: x as (Bt, H, S, P) with (1, 1, Q, P)
+    blocks, and the per-step dt and chunk-local log-decay cumsum L as
+    (Bt, H, S, 1) columns, so the last two dims of every block meet the
+    TPU's tiling rule. L is an O(S·H) cumsum done here in jnp (the TPU
+    kernel compiler has no cumsum); the kernel keeps the O(S·Q) work.
+    """
     Bt, S, H, P = x.shape
     N = B.shape[-1]
     Q = min(chunk, S)
@@ -78,19 +86,25 @@ def ssd_chunked_pallas(x, dt, A, B, C, *, chunk: int = 256, interpret: bool = Fa
     Sp = x.shape[1]
     nc = Sp // Q
 
+    xh = jnp.swapaxes(x, 1, 2)                                   # (Bt, H, Sp, P)
+    dth = jnp.swapaxes(dt, 1, 2).astype(jnp.float32)             # (Bt, H, Sp)
+    la = (dth * A.astype(jnp.float32)[None, :, None]).reshape(Bt, H, nc, Q)
+    L = jnp.cumsum(la, axis=-1).reshape(Bt, H, Sp)
+
     out = pl.pallas_call(
         functools.partial(_kernel, chunk=Q),
         grid=(Bt, H, nc),
         in_specs=[
-            pl.BlockSpec((1, Q, 1, P), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, Q, 1), lambda b, h, c: (b, c, h)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
+            pl.BlockSpec((1, 1, Q, P), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, Q, 1), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, Q, 1), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, Q, N), lambda b, h, c: (b, c, 0)),
             pl.BlockSpec((1, Q, N), lambda b, h, c: (b, c, 0)),
         ],
-        out_specs=pl.BlockSpec((1, Q, 1, P), lambda b, h, c: (b, c, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((Bt, Sp, H, P), x.dtype),
+        out_specs=pl.BlockSpec((1, 1, Q, P), lambda b, h, c: (b, h, c, 0)),
+        out_shape=jax.ShapeDtypeStruct((Bt, H, Sp, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A, B, C)
+    )(xh, dth[..., None], L[..., None], B, C)
+    out = jnp.swapaxes(out, 1, 2)
     return out[:, :S] if pad else out

@@ -15,9 +15,8 @@ multi-tenant path is exercisable standalone. ``--naive`` cross-checks the
 engine against the one-request-at-a-time loop (the pre-engine serving
 path) and reports token parity + speedup.
 
-On a real deployment the same prefill/decode step functions lower onto the
-production mesh (repro.launch.dryrun proves decode_32k/long_500k for every
-arch); here they run on host CPU at smoke scale.
+By default the backbone is the CPU smoke cut of ``--arch``;
+``--full-width`` builds the published config, which needs an accelerator.
 """
 from __future__ import annotations
 
@@ -27,8 +26,8 @@ import time
 import jax
 import numpy as np
 
-from repro.configs import get_smoke_config, list_archs
 from repro.core import adapters as nano
+from repro.launch.common import add_model_args, enable_compile_cache, model_config
 from repro.models import model as backbone_lib
 from repro.models.vision_stub import num_patches
 from repro.serving import (
@@ -73,7 +72,7 @@ def make_requests(cfg, tenants, n_requests, prefill_len, gen_tokens, seed):
 
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--arch", default="llava-1.5-7b", choices=list_archs())
+    add_model_args(ap)
     ap.add_argument("--tenants", type=int, default=4)
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--gen-tokens", type=int, default=8)
@@ -86,16 +85,18 @@ def main(argv=None):
                     help="directory of per-tenant federated checkpoints; "
                          "tenant names are the entries inside")
     ap.add_argument("--pallas-grouped", action="store_true",
-                    help="run the grouped-LoRA Pallas kernel (interpret "
-                         "mode on CPU) instead of the jnp reference")
+                    help="run the grouped-LoRA Pallas kernel (compiled on a "
+                         "TPU, interpreted elsewhere) instead of the jnp "
+                         "reference")
     ap.add_argument("--naive", action="store_true",
                     help="also run the one-request-at-a-time loop, check "
                          "token parity, and report the speedup")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     key = jax.random.PRNGKey(args.seed)
-    cfg = get_smoke_config(args.arch)
+    cfg = model_config(args)
     backbone = backbone_lib.init_backbone(key, cfg)
 
     if args.ckpt_root:
@@ -128,7 +129,8 @@ def main(argv=None):
     dt = time.time() - t0
     n_tok = sum(len(c.tokens) for c in done.values())
     print(f"arch={args.arch} engine: {len(reqs)} requests, {n_tok} tokens "
-          f"in {dt:.2f}s ({n_tok / dt:.1f} tok/s on 1 CPU core) | "
+          f"in {dt:.2f}s ({n_tok / dt:.1f} tok/s on "
+          f"{jax.devices()[0].device_kind}) | "
           f"occupancy {engine.mean_occupancy():.2f}/{args.slots} | "
           f"adapter cache {engine.cache.stats()}")
     for rid in sorted(done)[:4]:

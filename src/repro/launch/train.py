@@ -1,12 +1,12 @@
 """Federated training driver (``python -m repro.launch.train``).
 
-Runs the full FedNano protocol (or any baseline strategy) on a reduced
-backbone with the synthetic non-IID VQA corpus — the runnable end-to-end
-entry point (examples/federated_vqa.py wraps this with a narrative).
-
-On a real TPU fleet the same step functions lower onto the production mesh
-(see repro.launch.dryrun); here they run on host CPU with the smoke-scale
-configs. Checkpoints + metrics land under --out.
+Runs the full FedNano protocol (or any baseline strategy) with the
+synthetic non-IID VQA corpus — the runnable end-to-end entry point
+(examples/federated_vqa.py wraps this with a narrative). By default the
+backbone is the CPU smoke cut of ``--arch``; ``--full-width`` builds the
+published config (e.g. llava-1.5-7b: 32 layers, d_model 4096, bf16), which
+needs an accelerator. Checkpoints + metrics land under --out; a full-width
+run writes no backbone snapshot (its weights come from ``--seed``).
 """
 from __future__ import annotations
 
@@ -18,16 +18,16 @@ import time
 import jax
 
 from repro.checkpoint import save_server_checkpoint
-from repro.configs import get_smoke_config, list_archs
 from repro.core import FailureModel, HyperParams, run_centralized, run_federated
 from repro.data import make_federated_data
+from repro.launch.common import add_model_args, enable_compile_cache, model_config
 from repro.strategies import UniformSampler, available_strategies
 from repro.strategies.server_opt import FedAdamOpt, FedAvgMOpt
 
 
-def main(argv=None):
+def parse_args(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--arch", default="llava-1.5-7b", choices=list_archs())
+    add_model_args(ap)
     ap.add_argument("--strategy", default="fednano",
                     choices=list(available_strategies()) + ["centralized"])
     ap.add_argument("--server-opt", default=None, choices=["fedavgm", "fedadam"],
@@ -57,6 +57,8 @@ def main(argv=None):
     ap.add_argument("--clients", type=int, default=5)
     ap.add_argument("--rounds", type=int, default=10)
     ap.add_argument("--local-steps", type=int, default=8)
+    ap.add_argument("--fisher-batches", type=int, default=4,
+                    help="batches in the dedicated Fisher pass")
     ap.add_argument("--alpha", type=float, default=1.0)
     ap.add_argument("--lr", type=float, default=5e-3)
     ap.add_argument("--rank", type=int, default=None, help="NanoAdapter rank override")
@@ -83,25 +85,38 @@ def main(argv=None):
     ap.add_argument("--failure-seed", type=int, default=0,
                     help="seed for the failure schedule (independent of --seed)")
     ap.add_argument("--use-pallas", action="store_true",
-                    help="route LoRA/Fisher-merge through the Pallas kernels (interpret mode)")
-    args = ap.parse_args(argv)
+                    help="route attention/LoRA/Fisher-merge through the Pallas "
+                         "kernels (compiled on a TPU, interpreted elsewhere)")
+    return ap.parse_args(argv)
 
-    cfg = get_smoke_config(args.arch)
+
+def build_config(args):
+    cfg = model_config(args)
     if args.rank:
         import dataclasses
 
         cfg = cfg.with_(adapter=dataclasses.replace(cfg.adapter, rank=args.rank))
     if args.use_pallas:
         cfg = cfg.with_(use_pallas=True)
+    return cfg
 
-    print(f"== FedNano driver: arch={args.arch} (smoke config) strategy={args.strategy} "
+
+def run(args, cfg, *, server=None):
+    """Run the configured strategy; returns (FederatedResult, wall seconds).
+
+    ``server`` reuses an already-built server (its frozen backbone) instead
+    of initializing one from ``--seed``.
+    """
+    scale = "published config" if args.full_width else "smoke config"
+    print(f"== FedNano driver: arch={args.arch} ({scale}) strategy={args.strategy} "
           f"K={args.clients} R={args.rounds} α={args.alpha} rank={cfg.adapter.rank}")
     train, evald, _ = make_federated_data(
         cfg, n_clients=args.clients, examples_per_client=args.examples_per_client,
         alpha=args.alpha, batch_size=args.batch_size, seq_len=args.seq_len,
         seed=args.seed,
     )
-    hp = HyperParams(lr=args.lr, local_steps=args.local_steps)
+    hp = HyperParams(lr=args.lr, local_steps=args.local_steps,
+                     fisher_batches=args.fisher_batches)
     key = jax.random.PRNGKey(args.seed)
     t0 = time.time()
     if args.strategy == "centralized":
@@ -123,7 +138,7 @@ def main(argv=None):
                                     seed=args.failure_seed)
         res = run_federated(key, cfg, train, evald, strategy=args.strategy,
                             rounds=args.rounds, hp=hp, verbose=True,
-                            use_pallas=args.use_pallas,
+                            use_pallas=args.use_pallas, server=server,
                             server_opt=server_opt, sampler=sampler,
                             engine=args.engine, agg_chunk=args.agg_chunk,
                             devices=args.devices,
@@ -133,7 +148,14 @@ def main(argv=None):
                             checkpoint_dir=os.path.join(args.out, "state"),
                             checkpoint_every=args.checkpoint_every,
                             resume=args.resume)
-    dt = time.time() - t0
+    return res, time.time() - t0
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
+    res, dt = run(args, build_config(args))
+    key = jax.random.PRNGKey(args.seed)
 
     os.makedirs(args.out, exist_ok=True)
     summary = {
@@ -151,7 +173,7 @@ def main(argv=None):
         save_server_checkpoint(os.path.join(args.out, "ckpt"), res.server,
                                round_idx=args.rounds,
                                server_opt_state=res.server_opt_state,
-                               rng_key=key)
+                               rng_key=key, backbone=not args.full_width)
     print(f"== done in {dt:.1f}s: avg client accuracy {res.avg_accuracy:.4f}")
     print(f"   per-client: { {k: round(v, 4) for k, v in res.client_accuracy.items()} }")
     if res.comm_totals:

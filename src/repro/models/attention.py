@@ -198,7 +198,7 @@ def full_attention(cfg, params, x, angles, *, causal: bool = True,
 
         out = flash_ops.flash_attention(
             q, k, v, causal=True, window=cfg.sliding_window,
-            softcap=cfg.logit_softcap, interpret=True,
+            softcap=cfg.logit_softcap,
         )
     elif (
         causal

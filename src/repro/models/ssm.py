@@ -105,7 +105,7 @@ def ssm_apply(cfg, params, u, *, use_pallas: bool = False):
     if use_pallas:
         from repro.kernels.ssd_scan import ops as ssd_ops
 
-        y = ssd_ops.ssd(x, dt.astype(x.dtype), A, Bm, Cm, chunk=s.chunk_size, interpret=True)
+        y = ssd_ops.ssd(x, dt.astype(x.dtype), A, Bm, Cm, chunk=s.chunk_size)
     else:
         y = ssd_ref.ssd_chunked(x, dt.astype(x.dtype), A, Bm, Cm, chunk=s.chunk_size)
     y = y + x * params["D"][:, None].astype(x.dtype)

@@ -75,8 +75,7 @@ def grouped_adapter_apply(bank: AdapterBank, mod: str, x, idx, *,
     if use_pallas:
         from repro.kernels.lora import ops as lora_ops
 
-        return lora_ops.grouped_lora_residual(
-            x, down, up, idx, scale=bank.scale, interpret=True)
+        return lora_ops.grouped_lora_residual(x, down, up, idx, scale=bank.scale)
     from repro.kernels.lora import ref as lora_ref
 
     return lora_ref.grouped_lora_residual(x, down, up, idx, scale=bank.scale)

@@ -159,8 +159,7 @@ class ServingEngine:
                 from repro.kernels.lora import ops as lora_ops
 
                 flat = lora_ops.grouped_lora_residual(
-                    emb[:, 0, :], down, up, aslots, scale=bank.scale,
-                    interpret=True)
+                    emb[:, 0, :], down, up, aslots, scale=bank.scale)
             else:
                 from repro.kernels.lora import ref as lora_ref
 

@@ -26,8 +26,7 @@ def _fisher_fold_tree(num, den, theta, fisher, w, *, use_pallas=False):
         from repro.kernels.fisher_merge import ops as fm_ops
 
         folded = jax.tree.map(
-            lambda nm, dn, t, f: fm_ops.fisher_fold(nm, dn, t, f, w,
-                                                    interpret=True),
+            lambda nm, dn, t, f: fm_ops.fisher_fold(nm, dn, t, f, w),
             num, den, theta, fisher)
     else:
         folded = _fisher_fold_tree_jit(num, den, theta, fisher, w)

@@ -13,6 +13,7 @@ The golden fixture under ``tests/golden/run_state/`` (regenerate with
 meta.json fields, and leaf values. If this file's tests fail after a format
 change, bump ``RUN_STATE_VERSION`` and regenerate — loudly, on purpose.
 """
+import dataclasses
 import json
 import os
 
@@ -128,6 +129,20 @@ def test_server_checkpoint_preserves_opt_moments_and_rng(tmp_path, tiny_server):
     assert meta["round_idx"] == 3
     assert tree_allclose(meta["server_opt_state"], moments)
     assert np.array_equal(meta["rng_key"], np.asarray(key))
+    assert tree_allclose(restored.global_adapters, tiny_server.global_adapters)
+
+
+def test_server_checkpoint_without_backbone(tmp_path, tiny_server):
+    """A snapshot may leave out the frozen backbone; restoring keeps the
+    caller's (rebuilt from the seed) and still restores the adapters."""
+    d = str(tmp_path / "ckpt")
+    save_server_checkpoint(d, tiny_server, round_idx=2, backbone=False)
+    assert not os.path.exists(os.path.join(d, "backbone.npz"))
+    other = jax.tree.map(jnp.zeros_like, tiny_server.global_adapters)
+    restored, meta = load_server_checkpoint(
+        d, dataclasses.replace(tiny_server, global_adapters=other))
+    assert meta["has_backbone"] is False
+    assert restored.backbone is tiny_server.backbone
     assert tree_allclose(restored.global_adapters, tiny_server.global_adapters)
 
 

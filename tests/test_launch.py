@@ -94,3 +94,50 @@ def test_exec_config_modes():
     assert roof.attn_chunk is None and not roof.scan_layers
     over = steps_lib.exec_config(cfg, SHAPES["train"], "roofline", {"loss_chunk": 512})
     assert over.loss_chunk == 512
+
+
+@pytest.mark.parametrize("full", [False, True])
+def test_driver_model_config(full):
+    """One option picks the published config or its CPU smoke cut, for the
+    train and serve drivers alike."""
+    import argparse
+
+    from repro.configs import get_config
+    from repro.launch.common import add_model_args, model_config
+
+    ap = argparse.ArgumentParser()
+    add_model_args(ap)
+    args = ap.parse_args(["--arch", "llava-1.5-7b"] + (["--full-width"] if full else []))
+    cfg = model_config(args)
+    want = get_config("llava-1.5-7b") if full else get_smoke_config("llava-1.5-7b")
+    assert cfg == want
+    if full:
+        assert (cfg.n_layers, cfg.d_model, cfg.dtype) == (32, 4096, "bfloat16")
+
+
+@pytest.mark.parametrize("env_dir", [False, True])
+def test_compile_cache_dir(env_dir, tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise one fixed
+    directory at the checkout root, which git ignores."""
+    import os
+
+    from repro.launch.common import enable_compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    root = os.path.normpath(os.path.join(os.path.dirname(__file__), os.pardir))
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        path = enable_compile_cache()
+        if env_dir:
+            assert path == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == prev
+        else:
+            assert path == os.path.join(root, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+            with open(os.path.join(root, ".gitignore")) as f:
+                assert ".jax_cache/" in f.read().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

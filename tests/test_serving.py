@@ -169,12 +169,15 @@ def test_engine_stop_token_and_budget():
     free_run = ServingEngine(cfg, backbone, max_slots=2, prefill_len=8,
                              max_new_tokens=8,
                              adapter_loader=tenants.__getitem__).run(reqs)
-    stop = free_run[0].tokens[2]
+    toks = free_run[0].tokens
+    # stop on the first token (past the first) not seen earlier in the
+    # stream, so the run must end exactly there
+    cut = next(i for i in range(1, len(toks)) if toks[i] not in toks[:i])
     eng = ServingEngine(cfg, backbone, max_slots=2, prefill_len=8,
-                        max_new_tokens=8, stop_token=stop,
+                        max_new_tokens=8, stop_token=toks[cut],
                         adapter_loader=tenants.__getitem__)
     stopped = eng.run(_requests(cfg, [("alpha", 5, 6)]))
-    assert stopped[0].tokens == free_run[0].tokens[:3]
+    assert stopped[0].tokens == toks[:cut + 1]
     assert len(free_run[0].tokens) == 6  # budget respected
 
 
