@@ -14,8 +14,11 @@ One chip:
     4 clients x 2 rounds x 2 local steps + 2 Fisher batches, batch 4,
     seq_len 128 (+64 image patches); once with the Pallas kernels compiled
     and once without, on the same backbone. Every round loss must be finite
-    and the first round's mean loss of the two runs must agree within
-    ``LOSS_RTOL``.
+    and the two runs' round losses must agree (``ROUND0_RTOL``,
+    ``LATER_RTOL``). The gradient of one batch's loss at the trained
+    global adapters, taken through the Fisher pass's jitted gradient with
+    and without kernels, must agree leaf by leaf (``GRAD_RTOL``): the
+    backward through flash attention's custom VJP is checked there.
   * serving: ``ServingEngine`` on the trained backbone, with the global
     adapters and three clients' adapters as 4 tenants; 8 requests,
     prefill_len 64, 8 new tokens each, grouped-LoRA kernel compiled.
@@ -23,7 +26,8 @@ One chip:
 Four chips: the same trainer on ``engine="sharded"`` with 8 clients over a
 4-device ``("clients",)`` mesh against the same run with ``devices=1``
 (whose arrays are released first). Round losses must agree within
-``LOSS_RTOL`` and every stacked cohort output must span the 4 devices.
+``MESH_RTOL`` and every round's cohorts must span the 4 devices (the
+engine's ``cohort_devices`` round metric).
 
 Each phase prints its wall time, compile time and the device's
 ``peak_bytes_in_use``. The last line of stdout is the JSON verdict, printed
@@ -42,15 +46,23 @@ import os
 import sys
 import time
 
-# Pallas vs jnp (and mesh vs one device) reorder bf16 arithmetic; round
-# mean losses (~10.4 = ln 32000 for random weights) must agree to 2% — the
-# relative bf16 tolerance of the kernel harness
-LOSS_RTOL = 2e-2
-# Pallas vs jnp is compared on the first round only: AdamW's first steps
-# move each adapter weight by about lr·sign(g), so bf16-level gradient
-# differences flip whole updates and later rounds follow different
-# trajectories (round 2 came out 1.05 % and 2.26 % apart on two seeds)
-KERNEL_ROUNDS_COMPARED = 1
+# Limits, each set between what sound runs read on a v5e and what a broken
+# path gives. Pallas vs jnp differ by design: flash attention keeps an f32
+# online softmax where the jnp path rounds probabilities to bf16, so one
+# batch's adapter gradients differ by 1.5-2.1 % (relative L2 per leaf) and
+# round 0's mean loss (~ln 32000) by 6e-5 to 3.1e-4.
+ROUND0_RTOL = 2e-3
+# After the first merge the gap grows: AdamW's first step is lr·g/|g|, so
+# any gradient difference flips the update of the weights whose gradient
+# is near zero, and those flips compound. Swapping the jnp path's bf16
+# LoRA for f32 LoRA leaves one batch's loss and gradients bit-equal, yet
+# moves round 1 by 0.43 %; Pallas vs jnp read 1.05 % and 2.26 % there.
+LATER_RTOL = 5e-2
+# Per-leaf relative L2 gap of the Pallas gradient from the jnp gradient.
+GRAD_RTOL = 5e-2
+# Sharded vs one device runs the same per-client arithmetic; round losses
+# read 6.0e-5 to 1.7e-4 apart.
+MESH_RTOL = 1e-3
 
 ARCH = "llava-1.5-7b"
 SEED = 0
@@ -73,45 +85,30 @@ def _check(cond, msg):
         raise SmokeFailure(msg)
 
 
-class _Phase:
-    """Wall time, compile time and device peak memory of one phase."""
-
-    def __init__(self, name):
-        import jax
-
-        self.name = name
-        self.compile_s = 0.0
-        self._dev = jax.devices()[0]
-
-    def on_event(self, event, duration, **_):
-        if event.startswith("/jax/core/compile/"):
-            self.compile_s += duration
-
-    def __enter__(self):
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        wall = time.perf_counter() - self.t0
-        peak = (self._dev.memory_stats() or {}).get("peak_bytes_in_use")
-        peak_s = f"{peak} B ({peak / 2**30:.2f} GiB)" if peak else "not reported"
-        status = "failed" if exc[0] else "ok"
-        print(f"[{self.name}] {status}: wall {wall:.1f}s, compile "
-              f"{self.compile_s:.1f}s, peak_bytes_in_use {peak_s}", flush=True)
-        return False
-
-
 @contextlib.contextmanager
 def phase(name):
+    """Print one phase's wall time, compile time and device peak memory."""
     import jax
 
-    p = _Phase(name)
-    jax.monitoring.register_event_duration_secs_listener(p.on_event)
+    compile_s = 0.0
+
+    def on_event(event, duration, **_):
+        nonlocal compile_s
+        if event.startswith("/jax/core/compile/"):
+            compile_s += duration
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    status, t0 = "failed", time.perf_counter()
     try:
-        with p:
-            yield p
+        yield
+        status = "ok"
     finally:
-        jax.monitoring.unregister_event_duration_listener(p.on_event)
+        wall = time.perf_counter() - t0
+        jax.monitoring.unregister_event_duration_listener(on_event)
+        peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
+        peak_s = f"{peak} B ({peak / 2**30:.2f} GiB)" if peak else "not reported"
+        print(f"[{name}] {status}: wall {wall:.1f}s, compile {compile_s:.1f}s, "
+              f"peak_bytes_in_use {peak_s}", flush=True)
 
 
 def _round_losses(res, label):
@@ -122,12 +119,32 @@ def _round_losses(res, label):
     return losses
 
 
-def _agree(a, b, what):
+def _agree(a, b, what, rtols):
+    """Round r of ``a`` and ``b`` agree within ``rtols[min(r, last)]``."""
     _check(len(a) == len(b), f"{what}: round counts differ ({a} vs {b})")
     for r, (x, y) in enumerate(zip(a, b)):
-        _check(abs(x - y) <= LOSS_RTOL * abs(y),
+        rtol = rtols[min(r, len(rtols) - 1)]
+        _check(abs(x - y) <= rtol * abs(y),
                f"{what}: round {r} loss {x} vs {y} differs by more than "
-               f"rtol {LOSS_RTOL}")
+               f"rtol {rtol}")
+
+
+def _grad_gaps(kern_cfg, ref_cfg, backbone, adapters, batch):
+    """Relative L2 gap, per adapter leaf, of the Pallas gradient from jnp's."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.client import make_fisher_grad
+
+    kern = make_fisher_grad(kern_cfg)(backbone, adapters, batch)
+    ref = make_fisher_grad(ref_cfg)(backbone, adapters, batch)
+    gaps = {}
+    for (path, k), r in zip(jax.tree_util.tree_leaves_with_path(kern),
+                            jax.tree.leaves(ref)):
+        k, r = k.astype(jnp.float32), r.astype(jnp.float32)
+        gaps[jax.tree_util.keystr(path)] = float(
+            jnp.linalg.norm(k - r) / jnp.linalg.norm(r))
+    return gaps
 
 
 def _fresh(server):
@@ -159,8 +176,16 @@ def trainer_phase(width_argv):
     kern = _round_losses(res, "pallas kernels")
     ref_res, _ = train.run(ref_args, ref_cfg, server=_fresh(server0))
     ref = _round_losses(ref_res, "jnp reference")
-    _agree(kern[:KERNEL_ROUNDS_COMPARED], ref[:KERNEL_ROUNDS_COMPARED],
-           "pallas vs jnp")
+    _agree(kern, ref, "pallas vs jnp", (ROUND0_RTOL, LATER_RTOL))
+    del ref_res
+
+    batch = train.make_data(ref_args, ref_cfg)[0][0][0]
+    gaps = _grad_gaps(kern_cfg, ref_cfg, server0.backbone,
+                      res.server.global_adapters, batch)
+    print(f"  gradient gap, pallas vs jnp, relative L2 per leaf: {gaps}",
+          flush=True)
+    _check(all(math.isfinite(g) and g <= GRAD_RTOL for g in gaps.values()),
+           f"pallas vs jnp gradients differ by more than {GRAD_RTOL}: {gaps}")
     return kern_cfg, res
 
 
@@ -189,28 +214,6 @@ def serving_phase(cfg, res):
           f"{engine.cache.stats()}", flush=True)
 
 
-@contextlib.contextmanager
-def _record_cohort_devices(seen):
-    """Note how many devices each launched cohort's outputs span."""
-    import jax
-
-    from repro.core import client as client_lib
-
-    launch = client_lib.launch_cohort
-
-    def recording(prepared, backbone, global_adapters):
-        launched = launch(prepared, backbone, global_adapters)
-        for leaf in jax.tree.leaves(launched.outs):
-            seen.append(len(leaf.sharding.device_set))
-        return launched
-
-    client_lib.launch_cohort = recording
-    try:
-        yield
-    finally:
-        client_lib.launch_cohort = launch
-
-
 def sharded_phase(width_argv, n_devices=4):
     """engine="sharded" on ``n_devices`` vs the same run on one device."""
     from repro.launch import train
@@ -225,16 +228,14 @@ def sharded_phase(width_argv, n_devices=4):
     gc.collect()
 
     mesh_args = train.parse_args(argv + ["--devices", str(n_devices)])
-    seen = []
-    with _record_cohort_devices(seen):
-        res, _ = train.run(mesh_args, train.build_config(mesh_args))
+    res, _ = train.run(mesh_args, train.build_config(mesh_args))
     _agree(_round_losses(res, f"{n_devices} devices"), one,
-           f"{n_devices} devices vs 1 device")
-    _check(seen and set(seen) == {n_devices},
-           f"stacked cohort outputs span {sorted(set(seen))} devices, "
-           f"expected {n_devices}")
-    print(f"  {len(seen)} stacked cohort outputs, each across {n_devices} "
-          "devices", flush=True)
+           f"{n_devices} devices vs 1 device", (MESH_RTOL,))
+    spans = [m["cohort_devices"] for m in res.round_metrics]
+    _check(all(s == [n_devices] for s in spans),
+           f"cohort outputs span {spans} devices per round, expected "
+           f"[{n_devices}]")
+    print(f"  every round's cohorts span {n_devices} devices", flush=True)
 
 
 def main(argv=None):

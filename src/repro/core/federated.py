@@ -43,7 +43,9 @@ Four execution engines share those hooks:
     cached per chunk, and — when every upload is the raw adapter tree —
     aggregation runs device-side: all chunk outputs fold into the merge in
     one fused dispatch per round (padding rows zero-weighted), with losses
-    gathered in a single batched ``device_get``.
+    gathered in a single batched ``device_get``. Each round's metrics
+    list, under ``cohort_devices``, how many devices the stacked outputs
+    of its cohorts span.
   * ``engine="buffered"`` — FedBuff-style async simulation: clients run
     against the global version they last downloaded, a completion-ordered
     event loop fills a server buffer, and every ``buffer_size`` arrivals are
@@ -500,6 +502,7 @@ def _run_sync(
                 else:
                     cohort.append(cid)
         losses: List[float] = []           # cohort order
+        spans: set = set()                 # devices each mesh cohort spans
         updates: List[tuple] = []          # (theta, fisher, size), cohort order
         stream_acc = strat.agg_stream_init() if streaming else None
         stream_buf: List[tuple] = []
@@ -698,8 +701,12 @@ def _run_sync(
                     opt0_override=opt0, batches_override=bx)
                 if mesh is not None and bx is None:
                     batch_cache[ck] = prepared.args[4:7]
-                inflight.append((downloads, chunk, client_lib.launch_cohort(
-                    prepared, backbone_dev, global_dev)))
+                launched = client_lib.launch_cohort(
+                    prepared, backbone_dev, global_dev)
+                if mesh is not None:
+                    spans.update(len(leaf.sharding.device_set)
+                                 for leaf in jax.tree.leaves(launched.outs))
+                inflight.append((downloads, chunk, launched))
                 if len(inflight) >= depth:
                     collect_one()
             while inflight:
@@ -786,6 +793,8 @@ def _run_sync(
         if failures is not None:
             rm["dropped"] = n_dropped
             rm["crashed"] = n_crashed
+        if mesh is not None:
+            rm["cohort_devices"] = sorted(spans)
         result.round_metrics.append(rm)
         if verbose:
             shown = "skipped (no participants)" if n == 0 else f"mean local loss {rm['mean_loss']:.4f}"

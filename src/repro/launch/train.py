@@ -101,6 +101,16 @@ def build_config(args):
     return cfg
 
 
+def make_data(args, cfg):
+    """The run's (train, eval) batches per client, from ``--seed``."""
+    train, evald, _ = make_federated_data(
+        cfg, n_clients=args.clients, examples_per_client=args.examples_per_client,
+        alpha=args.alpha, batch_size=args.batch_size, seq_len=args.seq_len,
+        seed=args.seed,
+    )
+    return train, evald
+
+
 def run(args, cfg, *, server=None):
     """Run the configured strategy; returns (FederatedResult, wall seconds).
 
@@ -110,11 +120,7 @@ def run(args, cfg, *, server=None):
     scale = "published config" if args.full_width else "smoke config"
     print(f"== FedNano driver: arch={args.arch} ({scale}) strategy={args.strategy} "
           f"K={args.clients} R={args.rounds} α={args.alpha} rank={cfg.adapter.rank}")
-    train, evald, _ = make_federated_data(
-        cfg, n_clients=args.clients, examples_per_client=args.examples_per_client,
-        alpha=args.alpha, batch_size=args.batch_size, seq_len=args.seq_len,
-        seed=args.seed,
-    )
+    train, evald = make_data(args, cfg)
     hp = HyperParams(lr=args.lr, local_steps=args.local_steps,
                      fisher_batches=args.fisher_batches)
     key = jax.random.PRNGKey(args.seed)

@@ -81,6 +81,9 @@ def main():
             bad.append(f"losses {got_losses} vs {want['round_losses']}")
         if rel(res.avg_accuracy, want["avg_accuracy"]) > 1e-6:
             bad.append(f"acc {res.avg_accuracy} vs {want['avg_accuracy']}")
+        spans = [m["cohort_devices"] for m in res.round_metrics]
+        if any(s != [8] for s in spans):
+            bad.append(f"cohorts span {spans} devices, not [8] each round")
         if {str(k): v for k, v in res.comm_totals.items()} != \
                 {k: v for k, v in want["comm_totals"].items()}:
             bad.append(f"comm {res.comm_totals} vs {want['comm_totals']}")

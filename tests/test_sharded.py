@@ -112,6 +112,8 @@ def test_sharded_matches_vmap_one_device():
         [m["mean_loss"] for m in a.round_metrics],
         [m["mean_loss"] for m in b.round_metrics], rtol=1e-6)
     assert a.comm_totals == b.comm_totals
+    assert all(m["cohort_devices"] == [1] for m in b.round_metrics)
+    assert all("cohort_devices" not in m for m in a.round_metrics)
     np.testing.assert_allclose(a.avg_accuracy, b.avg_accuracy, rtol=1e-6)
     for x, y in zip(jax.tree.leaves(a.server.global_adapters),
                     jax.tree.leaves(b.server.global_adapters)):

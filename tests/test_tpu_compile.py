@@ -12,8 +12,6 @@ only one process may load the TPU library, and under several test workers
 only the worker that runs this file should. ``interpret=False`` is passed
 explicitly because the platform decision sees this host's CPU.
 """
-import os
-
 import jax
 import jax.numpy as jnp
 import pytest
@@ -35,24 +33,22 @@ def one_chip():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
-    # a compile for a described chip is written to the persistent cache but
-    # cannot be read back without one; keep the cache out of it
-    prev_cache = jax.config.jax_enable_compilation_cache
-    prev_log = os.environ.get("TPU_LOG_DIR")
-    os.environ["TPU_LOG_DIR"] = "disabled"
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # no TPU compiler in this installation
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", prev_cache)
-    if prev_log is None:
-        os.environ.pop("TPU_LOG_DIR", None)
-    else:
-        os.environ["TPU_LOG_DIR"] = prev_log
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler in this installation
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a compile for a described chip is written to the persistent cache
+        # but cannot be read back without one; keep the cache out of it
+        prev_cache = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", prev_cache)
 
 
 def _compile_has_kernel(fn, *shapes):
