@@ -1,0 +1,33 @@
+"""Published peaks per chip, keyed by the ``device_kind`` JAX reports.
+
+Source: Google Cloud documentation, "TPU v5e" (system architecture page):
+197 TFLOP/s bf16, 393 TOP/s int8, 16 GB of HBM at 819 GB/s per chip.
+JAX names this chip "TPU v5 lite". A device that is not in the table is an
+error: a roofline against a guessed peak would be a wrong number.
+"""
+from __future__ import annotations
+
+from typing import Dict
+
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {
+        "bf16_flops_per_s": 197e12,
+        "int8_ops_per_s": 393e12,
+        "hbm_bytes_per_s": 819e9,
+        "hbm_bytes": 16e9,
+    },
+}
+SOURCE = 'Google Cloud documentation, "TPU v5e"'
+
+
+class UnknownDevice(KeyError):
+    pass
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise UnknownDevice(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)}") from None
