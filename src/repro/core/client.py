@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+from collections import Counter
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -48,6 +49,8 @@ from repro.core import adapters as adapters_lib
 from repro.core.fisher import FisherAccumulator, fisher_pass
 from repro.core.types import Batch
 from repro.optim import adamw_init, adamw_update
+from repro.tracing import span
+from repro.utils import device_bytes, host_bytes, tree_bytes
 from repro.utils import tree_stack  # noqa: F401  (re-export for tests)
 
 
@@ -424,7 +427,7 @@ def make_many_update(cfg, strategy, hp: HyperParams, *, downloads: bool,
     return jax.jit(vm)
 
 
-def _host_stack(trees, *, to_device: bool = True):
+def _host_stack(trees, *, to_device: bool = True, moved: Optional[Dict] = None):
     """``tree_stack`` for the host side of the vmap path.
 
     ``jnp.stack`` over K device arrays and per-leaf device ops cost
@@ -435,29 +438,42 @@ def _host_stack(trees, *, to_device: bool = True):
     ``to_device=False`` keeps the stacked leaves as numpy: the sharded path
     scatters them straight to the mesh with one ``device_put`` per leaf, so
     the intermediate copy onto the default device would be pure waste.
+
+    ``moved`` (a ``Counter``) gains ``bytes_to_host``, what is fetched from
+    the device, and with ``to_device`` ``bytes_to_device``, the stacked
+    leaves placed.
     """
     td = jax.tree.structure(trees[0])
+    flat = [jax.tree.flatten(t)[0] for t in trees]
+    if moved is not None:
+        moved["bytes_to_host"] += device_bytes(flat)
     # one batched device_get (single sync) beats per-leaf np.asarray, which
     # pays ~100µs of sync overhead per call — O(K·leaves) of them here
-    flat = jax.device_get([jax.tree.flatten(t)[0] for t in trees])
+    flat = jax.device_get(flat)
     conv = jnp.asarray if to_device else (lambda x: x)
     leaves = [conv(np.stack(col)) for col in zip(*flat)]
+    if to_device and moved is not None:
+        moved["bytes_to_device"] += tree_bytes(leaves)
     return jax.tree.unflatten(td, leaves)
 
 
-def _host_unstack(tree, n: int):
+def _host_unstack(tree, n: int, moved: Optional[Dict] = None):
     """Inverse of :func:`_host_stack`: numpy views per client, no device ops.
 
     The returned per-client leaves are numpy arrays (views into the stacked
     result); downstream jax ops convert them back for free on CPU.
+    ``moved`` (a ``Counter``) gains ``bytes_to_host``, what is fetched.
     """
     leaves, td = jax.tree.flatten(tree)
+    if moved is not None:
+        moved["bytes_to_host"] += device_bytes(leaves)
     host = jax.device_get(leaves)
     return [jax.tree.unflatten(td, [h[i] for h in host]) for i in range(n)]
 
 
 def _stack_batch_rows(batch_lists: Sequence[List[Batch]], picks, *,
-                      shared: bool, to_device: bool = True):
+                      shared: bool, to_device: bool = True,
+                      moved: Optional[Dict] = None):
     """Stack per-client batch selections into scan xs.
 
     ``picks(batches)`` yields the Batch sequence one client scans over.
@@ -467,14 +483,14 @@ def _stack_batch_rows(batch_lists: Sequence[List[Batch]], picks, *,
     """
     if shared:
         row = list(picks(batch_lists[0]))
-        return _host_stack(row, to_device=to_device) if row else None
+        return _host_stack(row, to_device=to_device, moved=moved) if row else None
     rows = []
     for bl in batch_lists:
         row = list(picks(bl))
         if not row:
             return None
-        rows.append(_host_stack(row, to_device=False))
-    return _host_stack(rows, to_device=to_device)
+        rows.append(_host_stack(row, to_device=False, moved=moved))
+    return _host_stack(rows, to_device=to_device, moved=moved)
 
 
 @dataclass
@@ -519,6 +535,7 @@ def prepare_cohort(
     pad_to: Optional[int] = None,
     opt0_override=None,
     batches_override=None,
+    moved: Optional[Dict] = None,
 ) -> PreparedCohort:
     """Validate + stack a homogeneous cohort (the host half of a dispatch).
 
@@ -543,6 +560,9 @@ def prepare_cohort(
     ``(train_xs, warm_xs, fish_xs)`` triple for this exact cohort — client
     batch lists are immutable within a run, so the engine reuses the placed
     stacks across rounds instead of re-stacking identical data every round.
+
+    ``moved`` (a ``Counter``) gains ``bytes_to_device`` and
+    ``bytes_to_host``, the bytes this call copies each way.
     """
     from repro.sharding import CLIENT_AXIS, pad_to_multiple
     from repro.strategies.base import get_strategy
@@ -599,13 +619,13 @@ def prepare_cohort(
         try:
             train_xs = _stack_batch_rows(
                 batch_lists, lambda bl: (bl[t % len(bl)] for t in range(train_t)),
-                shared=shared, to_device=to_dev)
+                shared=shared, to_device=to_dev, moved=moved)
             warm_xs = _stack_batch_rows(
                 batch_lists, lambda bl: bl[:warm_t], shared=shared,
-                to_device=to_dev) if warmup else None
+                to_device=to_dev, moved=moved) if warmup else None
             fish_xs = _stack_batch_rows(
                 batch_lists, lambda bl: bl[:fish_t], shared=shared,
-                to_device=to_dev) if fish_t else None
+                to_device=to_dev, moved=moved) if fish_t else None
         except ValueError as e:  # jnp.stack shape mismatch
             raise ValueError(
                 "local_update_many needs identical batch shapes across the "
@@ -613,18 +633,19 @@ def prepare_cohort(
     if train_t > 0 and train_xs is None:
         raise ValueError("clients with no training batches cannot run local steps")
 
+    stack = functools.partial(_host_stack, to_device=to_dev, moved=moved)
     adapters0 = (None if downloads
-                 else _host_stack([s.adapters for s in states], to_device=to_dev))
+                 else stack([s.adapters for s in states]))
     opt0 = (opt0_override if opt0_override is not None
-            else _host_stack([s.opt_state for s in states], to_device=to_dev))
-    local0 = (_host_stack([s.local_adapters for s in states], to_device=to_dev)
+            else stack([s.opt_state for s in states]))
+    local0 = (stack([s.local_adapters for s in states])
               if has_local else None)
     lopt0 = None
     if warmup:
-        lopt0 = _host_stack([
+        lopt0 = stack([
             s.local_opt_state if s.local_opt_state is not None
             else adamw_init(s.local_adapters) for s in states
-        ], to_device=to_dev)
+        ])
 
     if mesh is not None:
         # direct host->device scatter per shard: each device receives only
@@ -632,6 +653,11 @@ def prepare_cohort(
         shd = NamedSharding(mesh, P(CLIENT_AXIS))
         rep = NamedSharding(mesh, P())
         bshard = rep if shared else shd
+        if moved is not None:
+            moved["bytes_to_device"] += host_bytes(
+                [adapters0, None if opt0_override is not None else opt0, local0,
+                 lopt0, None if batches_override is not None
+                 else (train_xs, warm_xs, fish_xs)])
         adapters0 = jax.device_put(adapters0, shd) if adapters0 is not None else None
         if opt0_override is None:  # an override is already mesh-placed
             opt0 = jax.device_put(opt0, shd)
@@ -686,32 +712,45 @@ def collect_cohort(launched: LaunchedCohort, *, with_opt: bool = True,
     caller takes ownership of ``launched.outs[1]`` — the stacked new opt
     tree, still on the devices — materializing rows only when a per-client
     value is actually needed (checkpointing, cohort reshuffle, run end).
+
+    The wait for the device is its own span (``fednano.round.wait``), ahead
+    of the unstack's (``fednano.round.unstack``), whose ``bytes_to_host``
+    counts what it fetches.
     """
     p = launched.prepared
     k = p.k
     new_adp, new_opt, new_local, new_lopt, fishers, losses = launched.outs
 
-    adp_list = _host_unstack(new_adp, k)
-    opt_list = _host_unstack(new_opt, k) if with_opt else None
-    local_list = _host_unstack(new_local, k) if p.has_local else [None] * k
-    lopt_list = _host_unstack(new_lopt, k) if p.warmup else [None] * k
-    fisher_list = (_host_unstack(fishers, k)
-                   if p.wants_fisher is not None else [None] * k)
+    with span("round.wait"):
+        jax.block_until_ready(launched.outs)
+    with span("round.unstack") as sp:
+        moved = Counter()
+        adp_list = _host_unstack(new_adp, k, moved)
+        opt_list = _host_unstack(new_opt, k, moved) if with_opt else None
+        local_list = _host_unstack(new_local, k, moved) if p.has_local else [None] * k
+        lopt_list = _host_unstack(new_lopt, k, moved) if p.warmup else [None] * k
+        fisher_list = (_host_unstack(fishers, k, moved)
+                       if p.wants_fisher is not None else [None] * k)
 
-    losses_np = (np.asarray(losses)[:k] if p.train_t > 0
-                 else np.zeros((k, 0), np.float32))
-    new_states, metrics = [], []
-    for i, s in enumerate(p.states):
-        new_states.append(dataclasses.replace(
-            s,
-            adapters=adp_list[i],
-            opt_state=opt_list[i] if with_opt else s.opt_state,
-            local_adapters=local_list[i] if p.has_local else s.local_adapters,
-            local_opt_state=lopt_list[i] if p.warmup else s.local_opt_state,
-            fisher=fisher_list[i],
-            rounds_participated=s.rounds_participated + 1,
-        ))
-    return new_states, _loss_metrics(losses_np)
+        if p.train_t > 0:
+            moved["bytes_to_host"] += device_bytes(losses)
+            losses_np = np.asarray(losses)[:k]
+        else:
+            losses_np = np.zeros((k, 0), np.float32)
+        new_states = []
+        for i, s in enumerate(p.states):
+            new_states.append(dataclasses.replace(
+                s,
+                adapters=adp_list[i],
+                opt_state=opt_list[i] if with_opt else s.opt_state,
+                local_adapters=local_list[i] if p.has_local else s.local_adapters,
+                local_opt_state=lopt_list[i] if p.warmup else s.local_opt_state,
+                fisher=fisher_list[i],
+                rounds_participated=s.rounds_participated + 1,
+            ))
+        metrics = _loss_metrics(losses_np)
+        sp.set_metadata(bytes_to_host=moved["bytes_to_host"])
+    return new_states, metrics
 
 
 def _loss_metrics(losses_np) -> List[Dict]:
@@ -758,8 +797,11 @@ def loss_metrics_deferred(loss_arrays, ks) -> List[List[Dict]]:
     """One batched gather of many chunks' device losses → per-chunk metric
     lists (same arithmetic as :func:`_loss_metrics`). ``ks`` holds each
     chunk's real (unpadded) client count; ``None`` entries (no train steps)
-    yield zero-loss metrics."""
-    gathered = jax.device_get([a for a in loss_arrays if a is not None])
+    yield zero-loss metrics. The gather is the host waiting on the round's
+    devices: a ``fednano.round.wait`` span with its ``bytes_to_host``."""
+    live = [a for a in loss_arrays if a is not None]
+    with span("round.wait", bytes_to_host=device_bytes(live)):
+        gathered = jax.device_get(live)
     it = iter(gathered)
     out = []
     for a, k in zip(loss_arrays, ks):

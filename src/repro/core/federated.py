@@ -71,7 +71,7 @@ import dataclasses
 import heapq
 import os
 import time
-from collections import deque
+from collections import Counter, deque
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Union
 
@@ -103,7 +103,8 @@ from repro.strategies.transforms import (
     UpdateTransform,
     default_transforms,
 )
-from repro.utils import tree_bytes
+from repro.tracing import span
+from repro.utils import host_bytes, tree_bytes
 
 ENGINES = ("sequential", "vmap", "sharded", "buffered")
 
@@ -413,6 +414,16 @@ def _chunks(seq: List, width: int):
         yield seq[i : i + width]
 
 
+def _rounds(sampler, cids, start: int, stop: int):
+    """(round, cohort) pairs; each round's body runs inside its
+    ``fednano.round`` span, which opens once the sampler has chosen the
+    cohort and closes when the loop asks for the next round."""
+    for r in range(start, stop):
+        cohort = list(sampler.select(r, cids))
+        with span("round", round=r, clients=len(cohort)):
+            yield r, cohort
+
+
 def _run_sync(
     cfg, server, strat, clients, cids, index_of, train_data, hp,
     transforms, tstates, server_opt, sampler, *, rounds, engine, agg_chunk,
@@ -453,16 +464,16 @@ def _run_sync(
     # reappears — cache them keyed by the exact chunk membership
     batch_cache: Dict[tuple, tuple] = {}
 
-    def materialize(cids_needed=None):
+    def materialize(cids_needed=None, moved=None):
         keys = ({home[c] for c in cids_needed if c in home}
                 if cids_needed is not None else set(home.values()))
         for ck in keys:
             ent = resident[ck]
             kk = ent["k"]
-            opt_rows = client_lib._host_unstack(ent["opt"], kk)
-            adp_rows = (client_lib._host_unstack(ent["adp"], kk)
+            opt_rows = client_lib._host_unstack(ent["opt"], kk, moved)
+            adp_rows = (client_lib._host_unstack(ent["adp"], kk, moved)
                         if ent["adp"] is not None else None)
-            fish_rows = (client_lib._host_unstack(ent["fish"], kk)
+            fish_rows = (client_lib._host_unstack(ent["fish"], kk, moved)
                          if ent["fish"] is not None else None)
             for j, c in enumerate(ck):
                 if home.get(c) != ck:
@@ -476,8 +487,7 @@ def _run_sync(
                     clients[index_of[c]], **fields)
                 del home[c]
 
-    for r in range(start_round, rounds):
-        cohort = list(sampler.select(r, cids))
+    for r, cohort in _rounds(sampler, cids, start_round, rounds):
         gbytes = tree_bytes(server.global_adapters)
         down_bytes = 0
         wire_up = 0
@@ -552,8 +562,9 @@ def _run_sync(
             stream_bytes["param_up"] += sum(tree_bytes(t) for t in ts)
             stream_bytes["fisher_up"] += sum(
                 tree_bytes(f) for f in fs if f is not None)
-            stream_acc = strat.agg_stream_fold(
-                stream_acc, ts, fs, ws, use_pallas=use_pallas)
+            with span("round.merge", bytes_to_device=host_bytes([ts, fs])):
+                stream_acc = strat.agg_stream_fold(
+                    stream_acc, ts, fs, ws, use_pallas=use_pallas)
             folded_any = True
             stream_buf.clear()
 
@@ -674,35 +685,41 @@ def _run_sync(
                         clients[index_of[c]] = ns
                     fast_losses.append((chunk, loss_dev, kc))
                     return
-                for c, ns, m in zip(chunk, new_states, mets):
-                    clients[index_of[c]] = ns
-                    pending[c] = m["loss_mean"]
-                    offer(c, ns, m["loss_mean"])
+                with span("round.offer", clients=kc):
+                    for c, ns, m in zip(chunk, new_states, mets):
+                        clients[index_of[c]] = ns
+                        pending[c] = m["loss_mean"]
+                        offer(c, ns, m["loss_mean"])
 
             for downloads, chunk in plan:
-                opt0 = bx = None
-                if mesh is not None:
-                    ck = tuple(chunk)
-                    bx = batch_cache.get(ck)
-                    if (all(home.get(c) == ck for c in chunk)
-                            and (downloads or resident[ck]["adp"] is None)):
-                        opt0 = resident[ck]["opt"]
-                    else:
-                        # cohort reshuffled (or stale adapters would be
-                        # stacked): pull resident rows back to their
-                        # ClientStates before stacking from the host
-                        needs = [c for c in chunk if c in home]
-                        if needs:
-                            materialize(needs)
-                idxs = [index_of[c] for c in chunk]
-                prepared = client_lib.prepare_cohort(
-                    cfg, [clients[i] for i in idxs],
-                    [train_data[c] for c in chunk], hp, strat, mesh=mesh,
-                    opt0_override=opt0, batches_override=bx)
-                if mesh is not None and bx is None:
-                    batch_cache[ck] = prepared.args[4:7]
-                launched = client_lib.launch_cohort(
-                    prepared, backbone_dev, global_dev)
+                with span("round.prepare", clients=len(chunk)) as sp:
+                    moved = Counter()
+                    opt0 = bx = None
+                    if mesh is not None:
+                        ck = tuple(chunk)
+                        bx = batch_cache.get(ck)
+                        if (all(home.get(c) == ck for c in chunk)
+                                and (downloads or resident[ck]["adp"] is None)):
+                            opt0 = resident[ck]["opt"]
+                        else:
+                            # cohort reshuffled (or stale adapters would be
+                            # stacked): pull resident rows back to their
+                            # ClientStates before stacking from the host
+                            needs = [c for c in chunk if c in home]
+                            if needs:
+                                materialize(needs, moved)
+                    idxs = [index_of[c] for c in chunk]
+                    prepared = client_lib.prepare_cohort(
+                        cfg, [clients[i] for i in idxs],
+                        [train_data[c] for c in chunk], hp, strat, mesh=mesh,
+                        opt0_override=opt0, batches_override=bx, moved=moved)
+                    if mesh is not None and bx is None:
+                        batch_cache[ck] = prepared.args[4:7]
+                    sp.set_metadata(bytes_to_device=moved["bytes_to_device"],
+                                    bytes_to_host=moved["bytes_to_host"])
+                with span("round.launch"):
+                    launched = client_lib.launch_cohort(
+                        prepared, backbone_dev, global_dev)
                 if mesh is not None:
                     spans.update(len(leaf.sharding.device_set)
                                  for leaf in jax.tree.leaves(launched.outs))
@@ -731,58 +748,61 @@ def _run_sync(
             # keep round metrics in cohort order regardless of grouping
             losses = [pending[c] for c in cohort if c in pending]
 
-        if fast_pend:
-            fast_acc = strat.agg_stream_fold_stacked(
-                None, [p[0] for p in fast_pend],
-                [p[1] for p in fast_pend], [p[2] for p in fast_pend],
-                use_pallas=use_pallas)
-        if fast_acc is not None:
-            # device-side stacked merge: finalize where the folds ran, then
-            # commit with byte totals identical to the per-client path
-            # (k identical rows ⇒ k·row_bytes)
-            prev_global = server.global_adapters
-            merged = strat.agg_stream_finalize(fast_acc, use_pallas=use_pallas)
-            server = server_lib.server_commit(
-                server, merged,
-                param_up=fast_bytes["param_up"],
-                fisher_up=fast_bytes["fisher_up"],
-                param_down=down_bytes, wire_up=wire_up,
-            )
-            if server_opt is not None:
-                new_global, opt_state = server_opt.apply(
-                    opt_state, prev_global, server.global_adapters
-                )
-                server = dataclasses.replace(server, global_adapters=new_global)
-        elif strat.aggregates and (updates or stream_buf or folded_any):
-            prev_global = server.global_adapters
-            if streaming:
-                fold_stream()
-                merged = strat.agg_stream_finalize(stream_acc, use_pallas=use_pallas)
+        if streaming:
+            fold_stream()  # the last part-chunk, in a merge span of its own
+        with span("round.merge",
+                  bytes_to_device=host_bytes([u[:2] for u in updates])):
+            if fast_pend:
+                fast_acc = strat.agg_stream_fold_stacked(
+                    None, [p[0] for p in fast_pend],
+                    [p[1] for p in fast_pend], [p[2] for p in fast_pend],
+                    use_pallas=use_pallas)
+            if fast_acc is not None:
+                # device-side stacked merge: finalize where the folds ran, then
+                # commit with byte totals identical to the per-client path
+                # (k identical rows ⇒ k·row_bytes)
+                prev_global = server.global_adapters
+                merged = strat.agg_stream_finalize(fast_acc, use_pallas=use_pallas)
                 server = server_lib.server_commit(
                     server, merged,
-                    param_up=stream_bytes["param_up"],
-                    fisher_up=stream_bytes["fisher_up"],
+                    param_up=fast_bytes["param_up"],
+                    fisher_up=fast_bytes["fisher_up"],
                     param_down=down_bytes, wire_up=wire_up,
                 )
-            else:
-                thetas = [u[0] for u in updates]
-                fishers = [u[1] for u in updates]
-                sizes = [u[2] for u in updates]
-                server = server_lib.server_aggregate(
-                    server, strat, thetas, fishers, sizes,
-                    use_pallas=use_pallas, wire_up=wire_up,
-                    down_bytes=down_bytes,
-                )
-            if server_opt is not None:
-                new_global, opt_state = server_opt.apply(
-                    opt_state, prev_global, server.global_adapters
-                )
-                server = dataclasses.replace(server, global_adapters=new_global)
-        elif down_bytes:
-            # no merge this round (e.g. LocFT, or every starter crashed) but
-            # clients still pulled the global at round start — that
-            # broadcast crossed the wire
-            server_lib.log_downloads(server, r, down_bytes)
+                if server_opt is not None:
+                    new_global, opt_state = server_opt.apply(
+                        opt_state, prev_global, server.global_adapters
+                    )
+                    server = dataclasses.replace(server, global_adapters=new_global)
+            elif strat.aggregates and (updates or stream_buf or folded_any):
+                prev_global = server.global_adapters
+                if streaming:
+                    merged = strat.agg_stream_finalize(stream_acc, use_pallas=use_pallas)
+                    server = server_lib.server_commit(
+                        server, merged,
+                        param_up=stream_bytes["param_up"],
+                        fisher_up=stream_bytes["fisher_up"],
+                        param_down=down_bytes, wire_up=wire_up,
+                    )
+                else:
+                    thetas = [u[0] for u in updates]
+                    fishers = [u[1] for u in updates]
+                    sizes = [u[2] for u in updates]
+                    server = server_lib.server_aggregate(
+                        server, strat, thetas, fishers, sizes,
+                        use_pallas=use_pallas, wire_up=wire_up,
+                        down_bytes=down_bytes,
+                    )
+                if server_opt is not None:
+                    new_global, opt_state = server_opt.apply(
+                        opt_state, prev_global, server.global_adapters
+                    )
+                    server = dataclasses.replace(server, global_adapters=new_global)
+            elif down_bytes:
+                # no merge this round (e.g. LocFT, or every starter crashed) but
+                # clients still pulled the global at round start — that
+                # broadcast crossed the wire
+                server_lib.log_downloads(server, r, down_bytes)
 
         n = len(losses)
         # an empty cohort must be distinguishable from a perfect round:
@@ -800,12 +820,13 @@ def _run_sync(
             shown = "skipped (no participants)" if n == 0 else f"mean local loss {rm['mean_loss']:.4f}"
             print(f"  [{strat.name}] round {r}: {shown}")
 
-        if ckpt is not None:
-            if home and ckpt.would_save(r + 1):
-                materialize()  # snapshots need true per-client state rows
-            ckpt.maybe_save(r + 1, server=server, clients=clients,
-                            tstates=tstates, opt_state=opt_state,
-                            metrics=result.round_metrics)
+        if ckpt is not None and ckpt.would_save(r + 1):
+            with span("round.checkpoint"):
+                if home:
+                    materialize()  # snapshots need true per-client state rows
+                ckpt.save(r + 1, server=server, clients=clients,
+                          tstates=tstates, opt_state=opt_state,
+                          metrics=result.round_metrics)
 
     if home:
         materialize()

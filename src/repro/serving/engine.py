@@ -47,6 +47,7 @@ from repro.serving.adapter_bank import (
     grouped_adapter_apply,
 )
 from repro.serving.kv_cache import KVSlotManager
+from repro.tracing import span
 
 
 @dataclass
@@ -214,7 +215,19 @@ class ServingEngine:
     def _admit(self, done: Dict[int, Completion]) -> None:
         while self._queue and self.slots.n_free > 0:
             r = self._queue.popleft()
-            aslot = self.cache.acquire(r.tenant)
+            with span("serve.admit", rid=r.rid, tenant=str(r.tenant),
+                      prompt_len=len(r.prompt)):
+                self._admit_one(r, done)
+
+    def _admit_one(self, r: Request, done: Dict[int, Completion]) -> None:
+        """Prefill one request into a free page; its spans nest in ``admit``."""
+        c = self.cache
+        with span("serve.adapter") as sp:
+            before = (c.hits, c.misses, c.evictions)
+            aslot = c.acquire(r.tenant)
+            sp.set_metadata(hit=c.hits - before[0], miss=c.misses - before[1],
+                            evicted=c.evictions - before[2])
+        with span("serve.prefill"):
             prompt = np.asarray(r.prompt, np.int32)
             L = len(prompt)
             tokens = np.zeros((1, self.prefill_len), np.int32)
@@ -227,12 +240,14 @@ class ServingEngine:
                 self.backbone, self.bank.data, jnp.int32(aslot),
                 jnp.asarray(tokens), patches, jnp.int32(last_idx))
             self.stats["prefills"] += 1
+        with span("serve.prefill.wait"):
             tok0 = int(tok0)
-            comp = Completion(rid=r.rid, tenant=r.tenant, tokens=[tok0])
-            if r.max_new_tokens <= 1 or tok0 == self.stop_token:
-                self.cache.release(r.tenant)
-                done[r.rid] = comp
-                continue
+        comp = Completion(rid=r.rid, tenant=r.tenant, tokens=[tok0])
+        if r.max_new_tokens <= 1 or tok0 == self.stop_token:
+            self.cache.release(r.tenant)
+            done[r.rid] = comp
+            return
+        with span("serve.page_write"):
             slot = self.slots.alloc()
             self.slots.write(slot, page, start_pos=last_idx + 1)
             self._aslot[slot] = aslot
@@ -243,12 +258,21 @@ class ServingEngine:
     def _step(self, done: Dict[int, Completion]) -> None:
         if not self._active:
             return
-        nxt, pool = self._decode_fn(
-            self.backbone, self.bank.data, self.slots.state,
-            jnp.asarray(self._last_tok), jnp.asarray(self.slots.pos),
-            jnp.asarray(self._aslot))
-        self.slots.state = pool
-        nxt = np.asarray(nxt)
+        with span("serve.decode", step=self.stats["decode_steps"],
+                  live=len(self._active)):
+            with span("serve.decode.dispatch"):
+                nxt, pool = self._decode_fn(
+                    self.backbone, self.bank.data, self.slots.state,
+                    jnp.asarray(self._last_tok), jnp.asarray(self.slots.pos),
+                    jnp.asarray(self._aslot))
+                self.slots.state = pool
+            with span("serve.decode.wait"):
+                nxt = np.asarray(nxt)
+            with span("serve.decode.bookkeep"):
+                self._bookkeep(nxt, done)
+
+    def _bookkeep(self, nxt: np.ndarray, done: Dict[int, Completion]) -> None:
+        """Append each live slot's token; retire finished requests."""
         self.stats["decode_steps"] += 1
         self.stats["occupancy_sum"] += len(self._active)
         for slot in sorted(self._active):

@@ -30,6 +30,18 @@ def tree_bytes(tree) -> int:
     return total
 
 
+def host_bytes(tree) -> int:
+    """Bytes of a pytree's NumPy leaves: what placing it on a device copies."""
+    return sum(x.nbytes for x in jax.tree_util.tree_leaves(tree)
+               if isinstance(x, (np.ndarray, np.generic)))
+
+
+def device_bytes(tree) -> int:
+    """Bytes of a pytree's ``jax.Array`` leaves: what fetching it to the host copies."""
+    return sum(x.nbytes for x in jax.tree_util.tree_leaves(tree)
+               if isinstance(x, jax.Array))
+
+
 def tree_zeros_like(tree):
     return jax.tree.map(jnp.zeros_like, tree)
 
