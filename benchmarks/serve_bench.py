@@ -64,7 +64,7 @@ def bench_tenants(cfg, backbone, n_tenants, n_requests, gen_tokens):
         max_new_tokens=gen_tokens, adapter_slots=max(SLOTS, 8),
         adapter_loader=adapters.__getitem__)
     engine.run(reqs)                       # warmup: compiles, discarded
-    engine.stats = {"decode_steps": 0, "prefills": 0, "occupancy_sum": 0}
+    engine.stats = dict.fromkeys(engine.stats, 0)
     t0 = time.time()
     got = engine.run(reqs)
     t_engine = time.time() - t0

@@ -251,27 +251,22 @@ def seed_cache(cfg, cache: KVCache, k, v, *, start: int = 0) -> KVCache:
     return KVCache(ck, cv)
 
 
-def decode_attention(cfg, params, x, angles, cache: KVCache, pos):
-    """One-token decode: x (B, 1, D), pos scalar int32 (absolute position).
-
-    Writes the new KV at slot ``pos % C`` (ring semantics — for full caches
-    C == seq_len so the slot is just ``pos``) and attends over valid slots.
-    Returns (out (B,1,D), new_cache).
-    """
-    B = x.shape[0]
-    hd = cfg.resolved_head_dim
-    C = cache.k.shape[1]
+def decode_qkv(cfg, params, x, angles):
+    """One token's rotated query and new K/V rows: x (B, 1, D) ->
+    q (B, 1, n_heads, hd), k and v (B, 1, n_kv, hd)."""
     q = _project_q(cfg, params, x)
     k, v = _project_kv(cfg, params, x)
     if angles is not None:
         q = apply_rotary(q, angles)
         k = apply_rotary(k, angles)
-    slot = jnp.mod(pos, C)
-    ck = jax.lax.dynamic_update_slice(cache.k, k.astype(cache.k.dtype), (0, slot, 0, 0))
-    cv = jax.lax.dynamic_update_slice(cache.v, v.astype(cache.v.dtype), (0, slot, 0, 0))
-    new_cache = KVCache(ck, cv)
-    # slot j valid iff it has been written: j <= pos (ring: pos >= C -> all valid)
-    valid = jnp.arange(C) <= pos  # (C,) — covers both ring and linear cases
+    return q, k, v
+
+
+def attend_cache(cfg, params, q, ck, cv, valid):
+    """One query per row against that row's cache: q (B, 1, n_heads, hd),
+    ck/cv (B, C, n_kv, hd), valid (B or 1, C) boolean. Returns (B, 1, D)."""
+    B = q.shape[0]
+    hd = cfg.resolved_head_dim
     nkv = cfg.n_kv_heads
     g = cfg.n_heads // nkv
     qg = q.reshape(B, 1, nkv, g, hd)
@@ -293,11 +288,71 @@ def decode_attention(cfg, params, x, angles, cache: KVCache, pos):
         "bqkgd,bskd->bkgqs", qg, ck, preferred_element_type=jnp.float32
     ) * (hd**-0.5)
     logits = _softcap(logits, cfg.logit_softcap)
-    logits = jnp.where(valid[None, None, None, None, :], logits, NEG_INF)
+    logits = jnp.where(valid[:, None, None, None, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(cv.dtype)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, cv)
     out = out.reshape(B, 1, cfg.n_heads * hd) @ params["wo"]
-    return constrain(out, ("data", None, None)), new_cache
+    return constrain(out, ("data", None, None))
+
+
+def decode_attention(cfg, params, x, angles, cache: KVCache, pos):
+    """One-token decode: x (B, 1, D), pos scalar int32 (absolute position).
+
+    Writes the new KV at slot ``pos % C`` (ring semantics — for full caches
+    C == seq_len so the slot is just ``pos``) and attends over valid slots.
+    Returns (out (B,1,D), new_cache).
+    """
+    C = cache.k.shape[1]
+    q, k, v = decode_qkv(cfg, params, x, angles)
+    slot = jnp.mod(pos, C)
+    ck = jax.lax.dynamic_update_slice(cache.k, k.astype(cache.k.dtype), (0, slot, 0, 0))
+    cv = jax.lax.dynamic_update_slice(cache.v, v.astype(cache.v.dtype), (0, slot, 0, 0))
+    # slot j valid iff it has been written: j <= pos (ring: pos >= C -> all valid)
+    valid = (jnp.arange(C) <= pos)[None]  # covers both ring and linear cases
+    return attend_cache(cfg, params, q, ck, cv, valid), KVCache(ck, cv)
+
+
+def page_decode_attention(cfg, params, x, angles, cache: KVCache, pos):
+    """One token per page against this layer's pages, which it only reads.
+
+    x (P, 1, D); cache k/v (P, C, n_kv, hd), this layer's pages where they
+    lie in the pool; pos (P,) int32, each page's position, -1 on a free
+    page. Each page's new K/V row takes the place of slot ``pos % C`` in
+    the attention, so the numbers are those of ``decode_attention``
+    writing it there first. Returns (out (P, 1, D), the KVCache of the new
+    rows (P, n_kv, hd)) for :func:`write_rows`.
+    """
+    C = cache.k.shape[1]
+    q, k, v = decode_qkv(cfg, params, x, angles)
+    k, v = k.astype(cache.k.dtype), v.astype(cache.v.dtype)
+    here = (jnp.arange(C)[None, :] == jnp.mod(pos, C)[:, None]) & (pos >= 0)[:, None]
+    ck = jnp.where(here[:, :, None, None], k, cache.k)
+    cv = jnp.where(here[:, :, None, None], v, cache.v)
+    valid = jnp.arange(C)[None, :] <= pos[:, None]
+    return attend_cache(cfg, params, q, ck, cv, valid), KVCache(k[:, 0], v[:, 0])
+
+
+def write_rows(pool: KVCache, rows: KVCache, pos) -> KVCache:
+    """Write every layer's new K/V row of each live page into the pool.
+
+    pool k/v (L, P, C, n_kv, hd); rows (L, P, n_kv, hd); pos (P,), -1 on a
+    free page, which is left as it is. One dynamic-update-slice of L rows
+    a page: in place when the pool's buffer is donated, and in the pool's
+    own layout. (A scatter, or a write that reads the pool at the rows it
+    writes, makes the TPU compiler lay the whole pool out anew, twice a
+    call.) A free page's slot 0 is read before any write and put back.
+    """
+    C = pool.k.shape[2]
+    slot = jnp.mod(jnp.maximum(pos, 0), C)
+    live = (pos >= 0)[None, :, None, None]
+
+    def put(a, r):
+        r = jnp.where(live, r.astype(a.dtype), a[:, :, 0])
+        for p in range(a.shape[1]):
+            a = jax.lax.dynamic_update_slice(a, r[:, p, None, None], (0, p, slot[p], 0, 0))
+        return a
+
+    return KVCache(put(pool.k, rows.k), put(pool.v, rows.v))
 
 
 def cross_decode_attention(cfg, params, x, mem_kv: KVCache):

@@ -132,6 +132,33 @@ def dec_step(cfg, stacks, x, state, pos):
     return x, {"layers": states}
 
 
+def dec_pages_step(cfg, stacks, x, pool, pos):
+    """The serving engine's decode: one token for each page of a pool.
+
+    x (P, 1, D); pos (P,) int32, -1 on a free page. The layer loop reads
+    the self-KV pages where they lie and emits each layer's new rows, which
+    are then written one per live page per layer
+    (``attention.write_rows``); the cross-KV is read-only and passes
+    through. Returns (x, pool).
+    """
+    layers = pool["layers"]
+
+    def body(c, inp):
+        lp, st = inp
+        h = norm(cfg, lp["norm1"], c)
+        out, rows = attn_lib.page_decode_attention(cfg, lp["self_attn"], h, None,
+                                                   st.self_kv, pos)
+        c = c + out
+        h = norm(cfg, lp["norm_x"], c)
+        c = c + attn_lib.cross_decode_attention(cfg, lp["cross_attn"], h, st.cross_kv)
+        c = c + mlp(cfg, lp["mlp"], norm(cfg, lp["norm2"], c))
+        return c, rows
+
+    x, rows = jax.lax.scan(body, x, (stacks["dec_layers"], layers))
+    self_kv = attn_lib.write_rows(layers.self_kv, rows, pos)
+    return x, {"layers": DecLayerState(self_kv=self_kv, cross_kv=layers.cross_kv)}
+
+
 def init_dec_state(cfg, batch: int, capacity: int, dtype):
     layers = []
     for _ in range(cfg.n_layers):

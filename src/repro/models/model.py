@@ -13,6 +13,7 @@ API (module-level functions, ``cfg`` first):
     logits(cfg, params, hidden)                  -> (B, S, V)
     prefill(cfg, params, embeds, positions, capacity, enc_embeds=None)
     decode_step(cfg, params, embed, state, pos)  -> (logits, state)
+    decode_pages(cfg, params, embed, pool, pos)  -> (logits, pool)
     init_state(cfg, batch, capacity, dtype)
 """
 from __future__ import annotations
@@ -174,6 +175,28 @@ def decode_step(cfg, params, embed, state, pos):
         x, state = transformer.decode_stack(cfg, params, x, angles, state, pos)
     hidden = norm(cfg, params["final_norm"], x)
     return logits(cfg, params, hidden), state
+
+
+def decode_pages(cfg, params, embed, pool, pos):
+    """One-token decode of every page of the serving engine's pool.
+
+    embed (P, 1, D), one token per page; pool: the stacked decode state of
+    P pages (``init_state(cfg, P, ...)``); pos (P,) int32, each page's
+    position, -1 on a free page. Each page decodes as ``decode_step`` would
+    decode it alone; the pool's KV is written one row per live page per
+    layer, and a free page's KV is left as it is.
+
+    Returns (logits (P, 1, V), pool).
+    """
+    positions = jnp.maximum(pos, 0)[:, None].astype(jnp.int32)
+    x = _add_learned_pos(cfg, params, embed, positions)
+    angles = make_angles(cfg, positions)
+    if cfg.family == "audio":
+        x, pool = encdec.dec_pages_step(cfg, params, x, pool, pos)
+    else:
+        x, pool = transformer.decode_pages_stack(cfg, params, x, angles, pool, pos)
+    hidden = norm(cfg, params["final_norm"], x)
+    return logits(cfg, params, hidden), pool
 
 
 def init_state(cfg, batch: int, capacity: int, dtype):

@@ -12,10 +12,12 @@ Layer bodies by family:
                 every sub-layer followed by its own MLP (Griffin residual
                 pattern); attn sub-layers use the local window.
 
-All three execution modes share layer params:
-    forward_stack   — full sequence, no state (training loss path)
-    prefill_stack   — full sequence, returns stacked decode state
-    decode_stack    — one token, consumes/produces stacked decode state
+All execution modes share layer params:
+    forward_stack      — full sequence, no state (training loss path)
+    prefill_stack      — full sequence, returns stacked decode state
+    decode_stack       — one token, consumes/produces stacked decode state
+    decode_pages_stack — one token per page of the serving engine's pool,
+                         which it writes one K/V row per page per layer
 """
 from __future__ import annotations
 
@@ -353,20 +355,25 @@ def prefill_stack(cfg, stack, x, angles, capacity: int, length=None):
 # decode: one token
 # ---------------------------------------------------------------------------
 
-def _attn_step(cfg, lp, x, angles, cache, pos):
-    h = norm(cfg, lp["norm1"], x)
-    out, cache = attn_lib.decode_attention(cfg, lp["attn"], h, angles, cache, pos)
-    x = x + out
-    x = x + mlp(cfg, lp["mlp"], norm(cfg, lp["norm2"], x))
-    return x, cache
+def _attn_step(cfg, lp, x, attend, cache, per_row: bool = False):
+    """Attention then MLP (or MoE) residual block of one decode token.
 
-
-def _moe_step(cfg, lp, x, angles, cache, pos):
-    h = norm(cfg, lp["norm1"], x)
-    out, cache = attn_lib.decode_attention(cfg, lp["attn"], h, angles, cache, pos)
+    ``attend(cfg, attn_params, h, cache) -> (out, kv)`` is the attention
+    sub-layer and what it emits for the cache. ``per_row`` routes each
+    row's token through the experts on its own, as a batch of one: expert
+    capacity is shared by the tokens of a batch, so pages decoded together
+    must not compete for it.
+    """
+    out, kv = attend(cfg, lp["attn"], norm(cfg, lp["norm1"], x), cache)
     x = x + out
-    y, _ = moe_apply(cfg, lp["moe"], norm(cfg, lp["norm2"], x))
-    return x + y, cache
+    h = norm(cfg, lp["norm2"], x)
+    if "moe" not in lp:
+        return x + mlp(cfg, lp["mlp"], h), kv
+    if per_row:
+        y = jax.vmap(lambda r: moe_apply(cfg, lp["moe"], r[None])[0][0])(h)
+    else:
+        y, _ = moe_apply(cfg, lp["moe"], h)
+    return x + y, kv
 
 
 def _rec_step(cfg, lp, x, state):
@@ -383,8 +390,13 @@ def _ssm_step(cfg, lp, x, state):
     return x + out, state
 
 
-def decode_stack(cfg, stack, x, angles, state, pos):
-    """x (B, 1, D), stacked state -> (hidden (B, 1, D), new state)."""
+def _decode_layers(cfg, stack, x, state, attend, per_row: bool = False):
+    """Layer loop of one decode token over the stacked state.
+
+    Each attention sub-layer's cache is replaced by what ``attend`` emits
+    for it (see ``_attn_step``); recurrent states by their new values.
+    Returns (hidden, emitted state).
+    """
     if cfg.family == "hybrid":
         acfg = _attn_cfg(cfg)
 
@@ -392,18 +404,17 @@ def decode_stack(cfg, stack, x, angles, state, pos):
             lp, st = inp
             c, s0 = _rec_step(cfg, lp["rec0"], c, st["rec0"])
             c, s1 = _rec_step(cfg, lp["rec1"], c, st["rec1"])
-            c, kv = _attn_step(acfg, lp["attn"], c, angles, st["attn"], pos)
+            c, kv = _attn_step(acfg, lp["attn"], c, attend, st["attn"])
             return c, {"rec0": s0, "rec1": s1, "attn": kv}
 
         x, st_t = _scan_emit(f, x, (stack["triples"], state["triples"]), cfg.scan_layers)
-        new_state = {"triples": st_t, "extras": None}
+        st_e = None
         if stack["extras"] is not None:
             def fe(c, inp):
                 lp, st = inp
                 return _rec_step(cfg, lp, c, st)
             x, st_e = _scan_emit(fe, x, (stack["extras"], state["extras"]), cfg.scan_layers)
-            new_state["extras"] = st_e
-        return x, new_state
+        return x, {"triples": st_t, "extras": st_e}
 
     if cfg.family == "ssm":
         def f(c, inp):
@@ -412,14 +423,47 @@ def decode_stack(cfg, stack, x, angles, state, pos):
         x, states = _scan_emit(f, x, (stack["layers"], state["layers"]), cfg.scan_layers)
         return x, {"layers": states}
 
-    step = _moe_step if cfg.family == "moe" else _attn_step
-
     def f(c, inp):
         lp, st = inp
-        return step(cfg, lp, c, angles, st, pos)
+        return _attn_step(cfg, lp, c, attend, st, per_row)
 
     x, caches = _scan_emit(f, x, (stack["layers"], state["layers"]), cfg.scan_layers)
     return x, {"layers": caches}
+
+
+def decode_stack(cfg, stack, x, angles, state, pos):
+    """x (B, 1, D), stacked state -> (hidden (B, 1, D), new state)."""
+    def attend(acfg, p, h, cache):
+        return attn_lib.decode_attention(acfg, p, h, angles, cache, pos)
+
+    return _decode_layers(cfg, stack, x, state, attend)
+
+
+def decode_pages_stack(cfg, stack, x, angles, pool, pos):
+    """The serving engine's decode: one token for each page of a pool.
+
+    x (P, 1, D); ``pool`` is the stacked decode state of P pages; pos (P,)
+    int32 is each page's position, -1 on a free page. Rows never mix: each
+    page decodes as it would in a batch of one. The layer loop only reads
+    the KV caches, where they lie, and emits each layer's new K/V rows;
+    after the loop they are written into the pool, one row per live page
+    per layer (``attention.write_rows``), so under a donated pool no
+    layer's pages are copied. Recurrent states, O(1) a page, are emitted
+    whole, a free page's with them: its next page write replaces them.
+    Returns (hidden (P, 1, D), pool).
+    """
+    def attend(acfg, p, h, cache):
+        return attn_lib.page_decode_attention(acfg, p, h, angles, cache, pos)
+
+    x, emitted = _decode_layers(cfg, stack, x, pool, attend, per_row=True)
+
+    def merge(old, new):
+        if isinstance(old, attn_lib.KVCache):
+            return attn_lib.write_rows(old, new, pos)
+        return new
+
+    is_kv = lambda a: isinstance(a, attn_lib.KVCache)
+    return x, jax.tree.map(merge, pool, emitted, is_leaf=is_kv)
 
 
 def init_decode_state(cfg, batch: int, capacity: int, dtype):

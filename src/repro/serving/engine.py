@@ -7,20 +7,27 @@ FedNano. The engine composes three pieces:
     per-tenant adapters hot-swapped from federated checkpoints into stacked
     bank arrays; the decode step selects them per row (grouped LoRA).
   * :class:`~repro.serving.kv_cache.KVSlotManager` — a fixed pool of decode
-    pages; admission = prefill into a free page, completion frees it.
+    pages; admission = prefill, then the page write installs the page in
+    place; completion frees it.
   * a continuous-batching loop: every engine step first admits queued
     requests into free pages, then runs ONE fixed-shape jitted decode step
-    over all pages (per-slot positions via vmap), so mixed-tenant,
-    mixed-length traffic never recompiles and never waits for the slowest
-    request of a static batch.
+    over all pages (per-page positions, ``model.decode_pages``), so
+    mixed-tenant, mixed-length traffic never recompiles and never waits for
+    the slowest request of a static batch.
+
+The pool is updated in place. The page write and the decode step donate
+it, and the decode step writes one K/V row per live page per layer into
+it, so no step copies the pool or a layer's pages. ``stats["pool_donations"]``
+counts the calls whose donation took effect (every decode step and page
+write, unless the backend refused a donation).
 
 Exactness: prompts are right-padded to ``prefill_len``. Under a causal mask
 pad rows never influence real rows, and pad KV written at slots
 ``[L_real, prefill_len)`` is only ever attended AFTER decode has overwritten
-it (decode at position p writes slot p before attending slots <= p), so the
-padded prefill + batched decode is token-identical to the one-request-at-a-
-time path — pinned by tests/test_serving.py. For ring-buffer (sliding-
-window) archs the same argument needs the padded prefill to fit the ring,
+it (decode at position p puts its own row in slot p before attending slots
+<= p), so the padded prefill + batched decode is token-identical to the
+one-request-at-a-time path — pinned by tests/test_serving.py. For ring-buffer
+(sliding-window) archs the same argument needs the padded prefill to fit the ring,
 which __init__ asserts. Recurrent-state families (ssm / hybrid) integrate
 every prefill step into their terminal state, so the engine passes the true
 prompt length down to ``model.prefill`` — recurrent sub-layers gate pad
@@ -46,7 +53,7 @@ from repro.serving.adapter_bank import (
     AdapterCache,
     grouped_adapter_apply,
 )
-from repro.serving.kv_cache import KVSlotManager
+from repro.serving.kv_cache import KVSlotManager, consumed
 from repro.tracing import span
 
 
@@ -120,7 +127,8 @@ class ServingEngine:
         self._active: Dict[int, Completion] = {}
         self._budget: Dict[int, int] = {}
         self._queue: "deque[Request]" = deque()
-        self.stats = {"decode_steps": 0, "prefills": 0, "occupancy_sum": 0}
+        self.stats = {"decode_steps": 0, "prefills": 0, "occupancy_sum": 0,
+                      "pool_donations": 0}
 
         capacity = self.capacity
 
@@ -168,25 +176,15 @@ class ServingEngine:
                     emb[:, 0, :], down, up, aslots, scale=bank.scale)
             return flat[:, None, :]
 
-        @jax.jit
+        @functools.partial(jax.jit, donate_argnums=2)
         def _decode(backbone_, bank_data, pool, toks, pos, aslots):
-            # ONE jitted step: embed -> grouped per-tenant adapter -> decode.
+            # ONE jitted step: embed -> grouped per-tenant adapter -> decode
+            # of every page; the donated pool is written by row in place.
             emb = model_lib.embed_tokens(cfg, backbone_, toks[:, None])
             emb = _apply_text_bank(bank_data, emb, aslots)
-
-            def one(page, e, p):
-                # vmap maps over the pool's batch axis (1); decode_step wants
-                # an explicit B=1 state, so re-insert/strip that axis here
-                page = jax.tree.map(lambda a: jnp.expand_dims(a, 1), page)
-                lg, page2 = model_lib.decode_step(
-                    cfg, backbone_, e[None, None], page, p)
-                page2 = jax.tree.map(lambda a: jnp.squeeze(a, 1), page2)
-                return lg[0], page2
-
-            lg, pool2 = jax.vmap(one, in_axes=(1, 0, 0), out_axes=(0, 1))(
-                pool, emb[:, 0, :], pos)
+            lg, pool = model_lib.decode_pages(cfg, backbone_, emb, pool, pos)
             nxt = jnp.argmax(lg[:, 0, :], axis=-1).astype(jnp.int32)
-            return nxt, pool2
+            return nxt, pool
 
         self._prefill_fn = _prefill
         self._decode_fn = _decode
@@ -247,9 +245,11 @@ class ServingEngine:
             self.cache.release(r.tenant)
             done[r.rid] = comp
             return
-        with span("serve.page_write"):
+        with span("serve.page_write") as sp:
             slot = self.slots.alloc()
-            self.slots.write(slot, page, start_pos=last_idx + 1)
+            donated = self.slots.write(slot, page, start_pos=last_idx + 1)
+            self.stats["pool_donations"] += donated
+            sp.set_metadata(donated=int(donated))
             self._aslot[slot] = aslot
             self._last_tok[slot] = tok0
             self._active[slot] = comp
@@ -259,13 +259,17 @@ class ServingEngine:
         if not self._active:
             return
         with span("serve.decode", step=self.stats["decode_steps"],
-                  live=len(self._active)):
+                  live=len(self._active)) as sp:
             with span("serve.decode.dispatch"):
-                nxt, pool = self._decode_fn(
-                    self.backbone, self.bank.data, self.slots.state,
-                    jnp.asarray(self._last_tok), jnp.asarray(self.slots.pos),
+                old = self.slots.state
+                nxt, self.slots.state = self._decode_fn(
+                    self.backbone, self.bank.data, old,
+                    jnp.asarray(self._last_tok),
+                    jnp.asarray(self.slots.decode_positions()),
                     jnp.asarray(self._aslot))
-                self.slots.state = pool
+                donated = consumed(old)
+            self.stats["pool_donations"] += donated
+            sp.set_metadata(donated=int(donated))
             with span("serve.decode.wait"):
                 nxt = np.asarray(nxt)
             with span("serve.decode.bookkeep"):

@@ -4,18 +4,24 @@ The engine owns ONE fixed-shape decode state for ``n_slots`` concurrent
 requests (the page pool) — for attention archs that is the stacked KV cache
 (L, n_slots, C, n_kv, hd); for SSM/RG-LRU archs the recurrent states; for
 enc-dec both self- and cross-KV. A request occupies exactly one page (slot)
-from admission to completion; prefill writes a freshly computed single-
-request state into its page, finishing frees the page for the next request
-in the queue. Because the pool's shape never changes, the jitted decode step
-is compiled once and mixed-length, mixed-tenant traffic never recompiles.
+from admission to completion; the page write installs a freshly computed
+single-request prefill state into its page, and finishing frees the page
+for the next request in the queue. Because the pool's shape never changes,
+the jitted decode step is compiled once and mixed-length, mixed-tenant
+traffic never recompiles.
+
+The pool is updated in place: the page write and the engine's decode step
+both donate it, and their output pool takes over its buffers, so neither
+copies the pool. ``consumed`` tells whether a donation took effect.
 
 Per-slot decode positions are tracked host-side: attention validity inside
-``decode_attention`` derives from the position (slot j valid iff j <= pos),
-so a freed page needs no scrubbing — its stale KV is unreachable until a new
-prefill overwrites the page wholesale.
+the decode derives from the position (slot j valid iff j <= pos), so a
+freed page needs no scrubbing — its stale KV is unreachable until the next
+page write installs a new page over it.
 """
 from __future__ import annotations
 
+import functools
 from typing import List, Optional
 
 import jax
@@ -25,13 +31,19 @@ from repro.models import model as model_lib
 from repro.utils import tree_bytes
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=0)
 def _write_page(pool, page, slot):
-    """Overwrite pool slot (batch axis 1 of every leaf) with a B=1 state."""
+    """Install a B=1 state in pool slot ``slot`` (batch axis 1 of every leaf),
+    in place: the pool is donated."""
     return jax.tree.map(
         lambda p, s: jax.lax.dynamic_update_index_in_dim(
             p, s[:, 0].astype(p.dtype), slot, axis=1),
         pool, page)
+
+
+def consumed(tree) -> bool:
+    """True when every leaf of ``tree`` was donated to a call and is gone."""
+    return all(leaf.is_deleted() for leaf in jax.tree.leaves(tree))
 
 
 class KVSlotManager:
@@ -62,10 +74,21 @@ class KVSlotManager:
         self._free.sort()  # deterministic reuse order
         self.pos[slot] = 0
 
-    def write(self, slot: int, page, start_pos: int) -> None:
-        """Install a single-request prefill state into ``slot``."""
-        self.state = _write_page(self.state, page, slot)
+    def write(self, slot: int, page, start_pos: int) -> bool:
+        """Install a single-request prefill state into ``slot``, in place.
+
+        Returns whether the old pool's buffers were donated to the write.
+        """
+        old = self.state
+        self.state = _write_page(old, page, slot)
         self.pos[slot] = start_pos
+        return consumed(old)
+
+    def decode_positions(self) -> np.ndarray:
+        """Each page's next decode position, -1 on a free page."""
+        pos = self.pos.copy()
+        pos[self._free] = -1
+        return pos
 
     def page_bytes(self) -> int:
         """Bytes of one page — what admitting a request actually costs."""

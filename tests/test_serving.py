@@ -10,6 +10,7 @@ against silent drift of both paths.
 import functools
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +20,7 @@ import pytest
 from repro.configs import get_smoke_config
 from repro.core import adapters as nano
 from repro.models import model as model_lib
+from repro.models.attention import KVCache
 from repro.models.vision_stub import num_patches
 from repro.serving import (
     AdapterBank,
@@ -137,6 +139,128 @@ def test_engine_pallas_grouped_matches_ref_path():
         runs[use_pallas] = eng.run(reqs)
     for r in reqs:
         assert runs[True][r.rid].tokens == runs[False][r.rid].tokens
+
+
+# ---------------------------------------------------------------------------
+# the pool is updated in place: donated, and written one row per page
+# ---------------------------------------------------------------------------
+
+def _aliased_outputs(compiled):
+    """Output indices the compiled program writes into a donated input."""
+    head = compiled.as_text().split("\n", 1)[0]
+    return {int(o) for o, _ in re.findall(r"\{(\d+)\}: \((\d+), \{\}", head)}
+
+
+@pytest.mark.parametrize("arch", ARCHS)
+def test_pool_donated_to_page_write_and_decode(arch):
+    from repro.serving.kv_cache import _write_page
+
+    cfg, backbone, tenants = _setup(arch)
+    eng = ServingEngine(cfg, backbone, max_slots=2, prefill_len=8,
+                        max_new_tokens=4, adapter_loader=tenants.__getitem__)
+    done = {}
+    for r in _requests(cfg, [("alpha", 5, 4), ("beta", 3, 4)]):
+        old = eng.slots.state
+        eng.submit(r)
+        eng._admit(done)
+        assert all(leaf.is_deleted() for leaf in jax.tree.leaves(old))
+        old = eng.slots.state
+        eng._step(done)
+        assert all(leaf.is_deleted() for leaf in jax.tree.leaves(old))
+    st = eng.stats
+    assert st["pool_donations"] == st["decode_steps"] + st["prefills"] == 4
+
+    # every pool leaf of the output is written into the donated input
+    pool = eng.slots.state
+    n = len(jax.tree.leaves(pool))
+    page = jax.tree.map(lambda a: a[:, :1], pool)
+    wp = _write_page.lower(pool, page, jnp.int32(0)).compile()
+    assert _aliased_outputs(wp) == set(range(n))
+    ints = jnp.zeros((2,), jnp.int32)
+    dec = eng._decode_fn.lower(eng.backbone, eng.bank.data, pool, ints, ints,
+                               ints - 1).compile()
+    assert _aliased_outputs(dec) == set(range(1, 1 + n))   # output 0: tokens
+
+
+def _kv_leaves(pool):
+    """(path, k or v leaf, read-only?) of every KV cache in the pool."""
+    out = []
+    flat = jax.tree_util.tree_flatten_with_path(
+        pool, is_leaf=lambda a: isinstance(a, KVCache))[0]
+    for path, node in flat:
+        if isinstance(node, KVCache):
+            cross = "cross_kv" in jax.tree_util.keystr(path)
+            out += [(jax.tree_util.keystr(path) + name, leaf, cross)
+                    for name, leaf in (("k", node.k), ("v", node.v))]
+    return out
+
+
+@pytest.mark.parametrize("arch", ["llava-1.5-7b", "h2o-danube-1.8b",
+                                  "recurrentgemma-9b", "whisper-base"])
+def test_decode_step_writes_one_row_per_live_page_per_layer(arch):
+    """Live pages at a young position, at the last slot, and (sliding-window
+    ring) past the window, beside a free page: each layer's KV changes in
+    exactly the row of each live page's position, to what ``decode_step``
+    writes for that page decoded alone; every other row, the free page
+    and the read-only cross-KV stay bit-equal."""
+    cfg, backbone, _ = _setup(arch)
+    eng = ServingEngine(cfg, backbone, max_slots=4, prefill_len=8,
+                        max_new_tokens=64)
+    key = jax.random.PRNGKey(5)
+    leaves, treedef = jax.tree.flatten(eng.slots.state)
+    pool = treedef.unflatten([
+        jax.random.normal(jax.random.fold_in(key, i), a.shape, a.dtype)
+        for i, a in enumerate(leaves)])
+    kv = _kv_leaves(pool)
+    C = next(leaf for _, leaf, cross in kv if not cross).shape[2]
+    ring = C < eng.capacity
+    assert ring == (arch in ("h2o-danube-1.8b", "recurrentgemma-9b"))
+    pos = np.array([3, -1, C - 1, C + 5 if ring else 7], np.int32)
+    live = [p for p in range(4) if pos[p] >= 0]
+    toks = np.array([11, 12, 13, 14], np.int32)
+    before = jax.tree.map(np.asarray, pool)
+    pages = {p: jax.tree.map(lambda a, p=p: a[:, p:p + 1], pool) for p in live}
+
+    nxt, after = eng._decode_fn(backbone, eng.bank.data, pool, jnp.asarray(toks),
+                                jnp.asarray(pos), jnp.full((4,), -1, jnp.int32))
+    refs = {}
+    for p in live:
+        emb = model_lib.embed_tokens(cfg, backbone, jnp.asarray(toks[p:p + 1, None]))
+        lg, refs[p] = model_lib.decode_step(cfg, backbone, emb, pages[p], jnp.int32(pos[p]))
+        assert int(jnp.argmax(lg[0, 0])) == int(nxt[p])
+    for (name, old, cross), (_, new, _) in zip(_kv_leaves(before), _kv_leaves(after)):
+        new = np.asarray(new)
+        if cross:
+            np.testing.assert_array_equal(new, old, err_msg=name)
+            continue
+        changed = np.any(new != old, axis=(3, 4))            # (L, P, C)
+        want = np.zeros_like(changed)
+        for p in live:
+            want[:, p, pos[p] % C] = True
+        np.testing.assert_array_equal(changed, want, err_msg=name)
+        for p in live:
+            ref = dict((n, leaf) for n, leaf, _ in _kv_leaves(refs[p]))[name]
+            np.testing.assert_allclose(new[:, p, pos[p] % C],
+                                       np.asarray(ref)[:, 0, pos[p] % C],
+                                       rtol=1e-5, atol=1e-5, err_msg=name)
+
+
+def test_moe_pages_do_not_share_expert_capacity():
+    """Eight pages on the same token pick the same expert. Tokens of one
+    batch share an expert's capacity, so decoded as one batch half of them
+    would be dropped; each page must decode as its request would alone."""
+    cfg, backbone, _ = _setup("llama4-scout-17b-a16e")
+    assert cfg.family == "moe"
+    pool = model_lib.init_state(cfg, 8, 16, model_lib.param_dtype(cfg))
+    emb = model_lib.embed_tokens(cfg, backbone, jnp.full((8, 1), 7, jnp.int32))
+    lg, _ = model_lib.decode_pages(cfg, backbone, emb, pool,
+                                   jnp.full((8,), 3, jnp.int32))
+    alone, _ = model_lib.decode_step(cfg, backbone, emb[:1],
+                                     jax.tree.map(lambda a: a[:, :1], pool),
+                                     jnp.int32(3))
+    for p in range(8):
+        np.testing.assert_allclose(np.asarray(lg[p]), np.asarray(alone[0]),
+                                   rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.smoke

@@ -207,6 +207,10 @@ def test_serving_spans_nest_and_carry_the_cache_counters(tmp_path):
 
     decodes = named(spans, "fednano.serve.decode")
     assert [s.args["step"] for s in decodes] == list(range(eng.stats["decode_steps"]))
+    # every decode step and page write consumed the pool it was given
+    writes = named(spans, "fednano.serve.page_write")
+    assert all(s.args["donated"] == 1 for s in decodes + writes)
+    assert len(decodes + writes) == eng.stats["pool_donations"]
     assert sum(s.args["live"] for s in decodes) == eng.stats["occupancy_sum"]
     for d in decodes:
         inner = [s.name for s in spans if s is not d and s.inside(d)]
