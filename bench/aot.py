@@ -67,7 +67,7 @@ def round_programs(cell, sz, cfg, chip):
     from repro.optim import adamw_init
     from repro.strategies import get_strategy
 
-    from bench.models import dense
+    from bench.models import common
 
     tr = cell.traffic
     k = tr["sampler"].get("n", tr["clients"])
@@ -78,11 +78,10 @@ def round_programs(cell, sz, cfg, chip):
     fn = make_many_update(cfg, get_strategy("fednano"), hp, downloads=True,
                           warmup=False, has_local=False, train_t=t, warm_t=0,
                           fish_t=f, shared_batches=False)
-    bb = jax.eval_shape(lambda key: dense._backbone.__wrapped__(key, sz, cell.config["dtype"]),
-                        jax.random.PRNGKey(0))
-    adp = jax.eval_shape(lambda: dense.adapter_set(0, sz, "global"))
+    bb = cell.model.backbone_shapes(sz, cell.config["dtype"])
+    adp = jax.eval_shape(lambda: common.adapter_set(0, sz, "global"))
     opt = jax.eval_shape(lambda: jax.vmap(lambda _: adamw_init(
-        jax.tree.map(jnp.zeros_like, dense.adapter_set(0, sz, "g"))))(jnp.arange(k)))
+        jax.tree.map(jnp.zeros_like, common.adapter_set(0, sz, "g"))))(jnp.arange(k)))
 
     def rows(n):
         sd = jax.ShapeDtypeStruct
@@ -107,15 +106,11 @@ def serve_programs(cell, sz, cfg, chip, slots):
     from repro.serving import ServingEngine
     from repro.serving.kv_cache import _write_page
 
-    from bench.models import dense
-
     tr = cell.traffic
     eng = ServingEngine(cfg, None, max_slots=1, prefill_len=tr["prefill_len"],
                         max_new_tokens=tr["output_len"]["max"],
                         adapter_slots=tr["adapter_slots"], use_pallas_grouped=True)
-    bb = _spec_tree(jax.eval_shape(
-        lambda key: dense._backbone.__wrapped__(key, sz, cell.config["dtype"]),
-        jax.random.PRNGKey(0)), chip)
+    bb = _spec_tree(cell.model.backbone_shapes(sz, cell.config["dtype"]), chip)
     bank = _spec_tree(eng.bank.data, chip)
     dt = model_lib.param_dtype(cfg)
     pool = _spec_tree(jax.eval_shape(
@@ -152,11 +147,10 @@ def main(argv=None):
     from jax.sharding import SingleDeviceSharding
 
     from bench import spec
-    from bench.models import dense
 
     jax.config.update("jax_enable_compilation_cache", False)
     cell = spec.load_cell(args.workload)
-    sz = dense.sizes(cell.config)
+    sz = cell.model.sizes(cell.config)
     extra = {"use_pallas": True}
     if args.layers:
         sz = dataclasses.replace(sz, layers=args.layers)

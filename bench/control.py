@@ -20,6 +20,9 @@ program's place:
   place. (The fault "a step returns its state unchanged" reads 1 on
   ``global_delta`` by construction, and "the merged update applied twice"
   reads 1 on ``global_delta`` and ``merge_last``; neither needs a run.)
+* ``fisher`` (round cells, both stand-ins): their Fisher pass over the
+  last checked cohort's rows against the reference's, both at the
+  window's starting adapters (in a run, the clients' own last adapters).
 
 The round cohorts are the window's: the mix's sampler at rounds 2 and 3,
 as ``bench/kinds/round.py`` draws them after its two rounds of set-up;
@@ -38,22 +41,26 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def round_rows(cell, seed):
     from bench import traffic_gen
     from bench.kinds import round as rk
-    from bench.models import dense
+    from bench.models import common
 
-    tr = cell.traffic
-    sz = dense.sizes(cell.config)
+    tr, model = cell.traffic, cell.model
+    sz = model.sizes(cell.config)
     pop = traffic_gen.round_population(seed, sz.vocab, sz.frontend, tr)
-    start = rk._host(dense.adapter_set(seed, sz, "global"))
+    start = rk._host(common.adapter_set(seed, sz, "global"))
     sampler = rk._sampler(tr, seed)
     cohorts = [list(sampler.select(2 + r, sorted(pop)))
                for r in range(rk.CHECKED_ROUNDS)]
-    round0 = None
+    last, round0 = cohorts[-1], None
+    thetas = [start] * len(last)
+    fisher = rk.reference_fisher(model, seed, sz, tr, pop, last, thetas)
     for name, kw in (("control_fp8", {"quant": "fp8"}), ("half", {"half": True})):
-        other = rk.reference_rounds(seed, sz, tr, pop, cohorts, start, **kw)
-        ref = rk.reference_rounds(seed, sz, tr, pop, cohorts, start,
+        other = rk.reference_rounds(model, seed, sz, tr, pop, cohorts, start, **kw)
+        ref = rk.reference_rounds(model, seed, sz, tr, pop, cohorts, start,
                                   g1=other["global"][0], round0=round0)
         round0 = ref["round0"]
-        yield {"seed": seed, "stand_in": name, **rk.compare(other, ref)}
+        f = rk.reference_fisher(model, seed, sz, tr, pop, last, thetas, **kw)
+        yield {"seed": seed, "stand_in": name, **rk.compare(other, ref),
+               "fisher": rk.fisher_gap(f, fisher, ref["keep"])}
 
 
 def serve_rows(cell, seed, seconds):
@@ -61,10 +68,9 @@ def serve_rows(cell, seed, seconds):
 
     from bench import harness, traffic_gen
     from bench.kinds import serve
-    from bench.models import dense
 
     tr = cell.traffic
-    sz = dense.sizes(cell.config)
+    sz = cell.model.sizes(cell.config)
     engine = serve.build(cell, seed)
     reqs = traffic_gen.serve_requests(seed, sz.vocab, tr, seconds)
     o = serve.offer(engine, reqs, seconds, harness.Tracer(False))
@@ -72,7 +78,7 @@ def serve_rows(cell, seed, seconds):
     del engine
     harness.free_device_memory()
     sample = serve.check_sample(seed, tr, o.finished, served)
-    gaps = serve.reference_gaps(seed, sz, tr, sample, served, quant="fp8")
+    gaps = serve.reference_gaps(cell.model, seed, sz, tr, sample, served, quant="fp8")
     yield {"seed": seed, "stand_in": "program",
            "logit_gap": float(max(np.max(g) for g, _ in gaps)),
            "tokens": int(sum(len(g) for g, _ in gaps))}

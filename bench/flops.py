@@ -1,15 +1,20 @@
 """Operations and bytes that the algorithm needs, from shapes alone.
 
+Each family module (``bench/models/<family>.py``) counts its own backbone
+and builds its ``round_flops``, ``prefill_cost``, ``decode_cost`` and
+``flash_launch_cost`` from the family-independent terms here: FedNano's
+adapters, the connector, the head, and a flash-attention call.
+
 Counting rules (a count that is too high would let a later change read
 more than 100 % of a peak; one that is too low would hide work):
 
 * Federated round, frozen backbone: the backward pass needs input gradients
   only, so a position costs 2 N forward + 2 N backward matmul FLOPs (N =
-  matmul parameters of the layers), plus causal attention (half of the
-  full score matrix), plus the head at the positions the loss reads, plus
-  the NanoAdapters (forward, input and weight gradients). Weight gradients
-  of the frozen layers (a further 2 N) and remat recomputation are not
-  counted.
+  matmul parameters of the layers, active ones only), plus causal
+  attention (half of the full score matrix), plus the head at the
+  positions the loss reads, plus the NanoAdapters (forward, input and
+  weight gradients). Weight gradients of the frozen layers (a further 2 N)
+  and remat recomputation are not counted.
 * Serving: a prefill needs its real prompt positions only (not the padding
   up to ``prefill_len``) and the head at the last one; a decode step needs
   one position per live slot. Needed bytes are the weights once per call
@@ -18,80 +23,41 @@ more than 100 % of a peak; one that is too low would hide work):
   4 B H Sq Sk D / 2; backward the four matmuls of dQ, dK, dV (P is not
   recomputed) 8 B H Sq Sk D / 2; bytes q, k, v, o and the LSE, plus do,
   dq, dk and dv in the backward pass.
+
+Only the fields every family's ``Sizes`` has are read here: ``d``,
+``vocab``, ``frontend``, ``rank`` and ``modalities``.
 """
 from __future__ import annotations
-
-from typing import Iterable
 
 BF16 = 2
 F32 = 4
 
 
-def layer_matmul_params(sz) -> int:
-    q, kv = sz.heads * sz.head_dim, sz.kv_heads * sz.head_dim
-    return sz.d * q + 2 * sz.d * kv + q * sz.d + 3 * sz.d * sz.ff
+def adapted_positions(sz, text_len: int, image_len: int) -> int:
+    """Positions of one sequence that a NanoAdapter of the config adapts."""
+    return text_len * ("text" in sz.modalities) + image_len * ("image" in sz.modalities)
 
 
-def layer_param_bytes(sz, dtype_bytes: int = BF16) -> int:
-    q, kv = sz.heads * sz.head_dim, sz.kv_heads * sz.head_dim
-    return (layer_matmul_params(sz) + q + 2 * kv + 2 * sz.d) * dtype_bytes
+def adapter_flops(sz, positions: int, backward: bool = False) -> int:
+    """NanoAdapters (d x r down, r x d up) at ``positions``: the forward,
+    and with ``backward`` their input and weight gradients too."""
+    return (12 if backward else 4) * sz.d * sz.rank * positions
 
 
-def weight_bytes(sz, dtype_bytes: int = BF16) -> int:
-    """Frozen weights a decode step or prefill reads once: the layers, the
-    head table and the final norm (embedding lookups read single rows)."""
-    return (sz.layers * layer_param_bytes(sz, dtype_bytes)
-            + (sz.vocab * sz.d + sz.d) * dtype_bytes)
+def connector_flops(sz, image_len: int) -> int:
+    """The frozen connector's forward at ``image_len`` patches (its input
+    is data, so it needs no gradient)."""
+    return 2 * sz.frontend * sz.d * image_len
 
 
-def attention_flops(sz, s: int, backward: bool) -> float:
-    """Causal self-attention scores and mixing, one sequence, all layers."""
-    per = 4 * sz.heads * sz.head_dim * s * s / 2
-    return sz.layers * per * (3 if backward else 1)
+def head_flops(sz, positions: int, backward: bool = False) -> int:
+    """The head (d x vocab) at ``positions``, and its input gradient with ``backward``."""
+    return (4 if backward else 2) * sz.d * sz.vocab * positions
 
 
-def round_flops(sz, *, sequences: int, text_len: int, image_len: int,
-                loss_positions: int) -> float:
-    """FLOPs a federated round needs for ``sequences`` forward+backward passes.
-
-    ``loss_positions`` is the total, over those sequences, of positions
-    whose label the loss reads.
-    """
-    s = text_len + image_len
-    n = sz.layers * layer_matmul_params(sz)
-    dense = 4 * n * s + attention_flops(sz, s, backward=True)
-    adapted = text_len * ("text" in sz.modalities) + image_len * ("image" in sz.modalities)
-    adapters = 12 * sz.d * sz.rank * adapted
-    connector = 2 * sz.frontend * sz.d * image_len
-    head = 4 * sz.d * sz.vocab
-    return sequences * (dense + adapters + connector) + head * loss_positions
-
-
-def prefill_cost(sz, length: int):
-    """(FLOPs, bytes) of one batch-1 prefill of ``length`` real positions."""
-    n = sz.layers * layer_matmul_params(sz)
-    flops = (2 * n * length + attention_flops(sz, length, backward=False)
-             + 2 * sz.d * sz.vocab + 4 * sz.d * sz.rank * length)
-    kv_bytes = kv_bytes_per_position(sz) * length
-    return flops, weight_bytes(sz) + kv_bytes
-
-
-def kv_bytes_per_position(sz, dtype_bytes: int = BF16) -> int:
-    return sz.layers * 2 * sz.kv_heads * sz.head_dim * dtype_bytes
-
-
-def decode_cost(sz, positions: Iterable[int]):
-    """(FLOPs, bytes) of one decode step over the live slots' positions.
-
-    A slot at position p attends p + 1 keys (its history and itself).
-    """
-    n = sz.layers * layer_matmul_params(sz)
-    flops = bytes_ = 0.0
-    for p in positions:
-        flops += (2 * n + sz.layers * 4 * sz.heads * sz.head_dim * (p + 1)
-                  + 2 * sz.d * sz.vocab + 4 * sz.d * sz.rank)
-        bytes_ += kv_bytes_per_position(sz) * (p + 1)
-    return flops, weight_bytes(sz) + bytes_
+def head_bytes(sz, dtype_bytes: int = BF16) -> int:
+    """The head table and the final norm."""
+    return (sz.vocab * sz.d + sz.d) * dtype_bytes
 
 
 def flash_cost(b: int, h: int, h_kv: int, sq: int, sk: int, d: int,

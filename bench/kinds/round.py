@@ -28,11 +28,18 @@ the program's state is freed:
   last round's clients hold in the program's result, against the global
   adapters the program ended with; per leaf, the norm of the change from
   the global adapters that round started from.
+* the last round's Fisher pass: the reference's Fisher diagonals at the
+  adapters each of those clients ended with, on the rows the program's pass
+  read, against the program's, per client and leaf (``fisher``). A Fisher
+  diagonal is a mean squared gradient, which a lower precision moves in
+  proportion; the merged change is AdamW's, whose first steps move every
+  entry by about the learning rate whatever its gradient.
 
 Each norm is compared as a gap relative to max(the reference leaf's norm,
-the median leaf's), the worst leaf taken. Leaves whose reference gradient
-(round 0's first step, all clients) is under a thousandth of the median
-leaf's are left out (none are, with ``up`` != 0 at the start).
+the median leaf's), the worst leaf (and client) taken. Leaves whose
+reference gradient (round 0's first step, all clients) is under a
+thousandth of the median leaf's are left out (none are, with ``up`` != 0
+at the start).
 """
 from __future__ import annotations
 
@@ -43,8 +50,8 @@ from typing import Dict, List
 
 import numpy as np
 
-from bench import flops, harness, traffic_gen
-from bench.models import dense
+from bench import harness, traffic_gen
+from bench.models import common
 
 EXCLUDE_BELOW = 1e-3
 CHECKED_ROUNDS = 2
@@ -140,16 +147,16 @@ def run(cell, args, t_start: float, devices, tracer: harness.Tracer):
     from repro.core.federated import run_federated
     from repro.core.server import ServerState
 
-    tr, seed = cell.traffic, args.seed
-    sz = dense.sizes(cell.config)
+    tr, seed, model = cell.traffic, args.seed, cell.model
+    sz = model.sizes(cell.config)
     cfg = cell.model_config(use_pallas=True)
     sharding = None
     if tr["engine"] == "sharded":
         from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
         sharding = NamedSharding(Mesh(np.array(devices), ("clients",)), PartitionSpec())
-    backbone = dense.backbone_weights(seed, sz, cell.config["dtype"], sharding)
-    global0 = dense.adapter_set(seed, sz, "global")
+    backbone = model.backbone_weights(seed, sz, cell.config["dtype"], sharding)
+    global0 = common.adapter_set(seed, sz, "global")
     pop = traffic_gen.round_population(seed, sz.vocab, sz.frontend, tr)
     data = {cid: traffic_gen.to_batches(rows) for cid, rows in pop.items()}
     hp = HyperParams(lr=tr["lr"], grad_clip=tr["grad_clip"], weight_decay=0.0,
@@ -160,7 +167,7 @@ def run(cell, args, t_start: float, devices, tracer: harness.Tracer):
               agg_chunk=tr.get("agg_chunk"), final_eval=False)
     if tr["engine"] == "sharded":
         kw["devices"] = len(devices)
-    key = dense.seed_key(seed)
+    key = common.seed_key(seed)
     server = ServerState(cfg=cfg, backbone=backbone, global_adapters=global0)
 
     # two rounds: the first compiles, the second (warm) sizes the window
@@ -195,7 +202,7 @@ def run(cell, args, t_start: float, devices, tracer: harness.Tracer):
         a, b = _round_work(pop, cohort, tr)
         seqs, loss_pos = seqs + a, loss_pos + b
     tokens = seqs * (tr["text_len"] + image_len(sz))
-    need_flops = flops.round_flops(sz, sequences=seqs, text_len=tr["text_len"],
+    need_flops = model.round_flops(sz, sequences=seqs, text_len=tr["text_len"],
                                    image_len=image_len(sz), loss_positions=loss_pos)
 
     rec = {r: _host(t) for r, t in recorder.merged.items()}
@@ -215,10 +222,12 @@ def run(cell, args, t_start: float, devices, tracer: harness.Tracer):
     harness.free_device_memory()
 
     t_ref = time.perf_counter()
-    ref = reference_rounds(seed, sz, tr, pop, s2.cohorts[:CHECKED_ROUNDS], start,
+    ref = reference_rounds(model, seed, sz, tr, pop, s2.cohorts[:CHECKED_ROUNDS], start,
                            g1=got["start"][1])
     readings = compare(got, ref)
     readings["merge_last"] = last_merge_gap(got["last"], ref["keep"])
+    fisher = reference_fisher(model, seed, sz, tr, pop, last, got["last"]["thetas"])
+    readings["fisher"] = fisher_gap(got["last"]["fishers"], fisher, ref["keep"])
     ref_s = time.perf_counter() - t_ref
 
     result = {
@@ -227,7 +236,7 @@ def run(cell, args, t_start: float, devices, tracer: harness.Tracer):
                   * len(s2.cohorts[0]),
         "device": device,
     }
-    ctx = {"kind": "round", "sz": sz, "traffic": tr, "chips": len(devices),
+    ctx = {"kind": "round", "model": model, "sz": sz, "traffic": tr, "chips": len(devices),
            "device_kind": devices[0].device_kind, "window_s": window_s,
            "tokens": tokens, "rounds": n_rounds, "need_flops": need_flops,
            "sequences": seqs, "trace": tracer.summary,
@@ -299,26 +308,56 @@ def _reference_round(ref, tr, pop, cohort, start, carry, half):
         losses.append(np.asarray(loss))
         if grad1 is None:
             grad1 = grad
-        theta, m, v = dense.adamw_step(grad, m, v, theta, jnp.asarray(done + t + 1),
-                                       lr=tr["lr"], grad_clip=tr["grad_clip"])
+        theta, m, v = common.adamw_step(grad, m, v, theta, jnp.asarray(done + t + 1),
+                                        lr=tr["lr"], grad_clip=tr["grad_clip"])
+    fisher = _fisher(ref, fish, theta)
+    rows = lambda tree: [jax.tree.map(lambda x, i=i: x[i], tree) for i in range(k)]
+    merged = common.fisher_merge(rows(theta)[:keep], rows(fisher)[:keep],
+                                 [pop[c].tokens.shape[0] for c in cohort[:keep]])
+    ms, vs = rows(m), rows(v)
+    out_carry = {c: (ms[i], vs[i], int(done[i]) + steps) for i, c in enumerate(cohort)}
+    return float(np.mean(np.stack(losses))), merged, grad1, out_carry
+
+
+def _fisher(ref, fish, theta):
+    """Diagonal Fisher of each client (stacked) at ``theta`` over the
+    (K, n, B, ...) rows ``fish``: the mean squared gradient, plus 1e-8."""
+    import jax
+    import jax.numpy as jnp
+
     fsum = jax.tree.map(jnp.zeros_like, theta)
     nf = fish[0].shape[1]
     for f in range(nf):
         sl = [None if a is None else a[:, f] for a in fish]
         _, grad = ref.loss_and_grads(theta, *sl)
         fsum = jax.tree.map(lambda s, x: s + x * x, fsum, grad)
-    fisher = jax.tree.map(lambda s: s / max(nf, 1) + 1e-8, fsum)
-    rows = lambda tree: [jax.tree.map(lambda x, i=i: x[i], tree) for i in range(k)]
-    merged = dense.fisher_merge(rows(theta)[:keep], rows(fisher)[:keep],
-                                [pop[c].tokens.shape[0] for c in cohort[:keep]])
-    ms, vs = rows(m), rows(v)
-    out_carry = {c: (ms[i], vs[i], int(done[i]) + steps) for i, c in enumerate(cohort)}
-    return float(np.mean(np.stack(losses))), merged, grad1, out_carry
+    return jax.tree.map(lambda s: s / max(nf, 1) + 1e-8, fsum)
 
 
-def reference_rounds(seed, sz, tr, pop, cohorts, start, g1=None, quant=None,
+def reference_fisher(model, seed, sz, tr, pop, cohort, thetas, quant=None,
+                     half=False) -> List[Dict]:
+    """The reference's Fisher diagonals of ``cohort`` at the adapters
+    ``thetas`` (a tree a client), over the rows the program's Fisher pass
+    reads; ``quant`` and ``half`` (for batches of two rows or more) as in
+    ``reference_rounds``."""
+    import jax
+    import jax.numpy as jnp
+
+    fb = tr["fisher_batches"]
+    fish = _stack_rows(pop, cohort, lambda nb: list(range(min(nb, fb))))
+    if half and tr["batch"] >= 2:
+        fish = tuple(None if a is None else a[:, :, : tr["batch"] // 2] for a in fish)
+    theta = jax.tree.map(lambda *xs: jnp.stack([jnp.asarray(x, jnp.float32) for x in xs]),
+                         *thetas)
+    with jax.default_matmul_precision("highest"):
+        fisher = _host(_fisher(model.Reference(seed, sz, quant), fish, theta))
+    return [jax.tree.map(lambda x, i=i: x[i], fisher) for i in range(len(cohort))]
+
+
+def reference_rounds(model, seed, sz, tr, pop, cohorts, start, g1=None, quant=None,
                      half=False, round0=None) -> Dict:
-    """The reference over rounds 0 and 1 of ``cohorts``, from ``start``.
+    """The reference of family module ``model`` over rounds 0 and 1 of
+    ``cohorts``, from ``start``.
 
     Round 1 starts from ``g1`` (the merge of round 0 that the stand-in in
     the program's place made), or from the reference's own merge when
@@ -331,7 +370,7 @@ def reference_rounds(seed, sz, tr, pop, cohorts, start, g1=None, quant=None,
     import jax
 
     with jax.default_matmul_precision("highest"):
-        ref = dense.Reference(seed, sz, quant)
+        ref = model.Reference(seed, sz, quant)
         if round0 is None:
             loss0, merged0, grad1, carry = _reference_round(
                 ref, tr, pop, cohorts[0], start, {}, half)
@@ -383,8 +422,13 @@ def compare(got: Dict, ref: Dict) -> Dict[str, float]:
     return {"loss": loss, "global_delta": delta}
 
 
+def fisher_gap(prog: List[Dict], ref: List[Dict], keep: List[str]) -> float:
+    """Worst over clients and kept leaves of the gap of Fisher norms."""
+    return max((worst_gap(p, r, keep) for p, r in zip(prog, ref)), default=0.0)
+
+
 def last_merge_gap(last: Dict, keep: List[str]) -> float:
     """The program's last merge against Eq. 1 over its clients' final state."""
-    want = dense.fisher_merge(last["thetas"], last["fishers"], last["sizes"])
+    want = common.fisher_merge(last["thetas"], last["fishers"], last["sizes"])
     return worst_gap(_minus(last["global"], last["start"]),
                      _minus(want, last["start"]), keep)

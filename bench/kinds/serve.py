@@ -22,7 +22,6 @@ which a served token's reference logit lies below the reference's best.
 """
 from __future__ import annotations
 
-import functools
 import math
 import time
 from collections import deque
@@ -32,8 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import flops, harness, traffic_gen
-from bench.models import dense
+from bench import harness, traffic_gen
+from bench.models import common
 
 DRAIN_LIMIT_S = 60.0
 
@@ -113,10 +112,10 @@ def build(cell, seed: int):
     from repro.serving import ServingEngine
 
     tr = cell.traffic
-    sz = dense.sizes(cell.config)
+    sz = cell.model.sizes(cell.config)
     cfg = cell.model_config(use_pallas=True)
-    backbone = dense.backbone_weights(seed, sz, cell.config["dtype"])
-    tenants = {t: dense.adapter_set(seed, sz, t) for t in traffic_gen.tenant_names(tr)}
+    backbone = cell.model.backbone_weights(seed, sz, cell.config["dtype"])
+    tenants = {t: common.adapter_set(seed, sz, t) for t in traffic_gen.tenant_names(tr)}
     engine = ServingEngine(
         cfg, backbone, max_slots=tr["slots"], prefill_len=tr["prefill_len"],
         max_new_tokens=tr["output_len"]["max"], adapter_slots=tr["adapter_slots"],
@@ -183,8 +182,8 @@ def offer(engine, reqs, seconds: float, tracer) -> Offered:
 def run(cell, args, t_start: float, devices, tracer: harness.Tracer):
     from bench import peaks as peaks_lib
 
-    tr, seed = cell.traffic, args.seed
-    sz = dense.sizes(cell.config)
+    tr, seed, model = cell.traffic, args.seed, cell.model
+    sz = model.sizes(cell.config)
     engine = build(cell, seed)
     reqs = traffic_gen.serve_requests(seed, sz.vocab, tr, args.seconds)
     tracer.start()
@@ -200,16 +199,16 @@ def run(cell, args, t_start: float, devices, tracer: harness.Tracer):
     pk = peaks_lib.peaks(devices[0].device_kind)
     bound_s = 0.0
     for length in rec.prefill_lens:
-        f, b = flops.prefill_cost(sz, length)
+        f, b = model.prefill_cost(sz, length)
         bound_s += max(f / pk["bf16_flops_per_s"], b / pk["hbm_bytes_per_s"])
     for pos in rec.step_positions:
-        f, b = flops.decode_cost(sz, pos)
+        f, b = model.decode_cost(sz, pos)
         bound_s += max(f / pk["bf16_flops_per_s"], b / pk["hbm_bytes_per_s"])
     del engine
     harness.free_device_memory()
 
     t_ref = time.perf_counter()
-    readings = check_served(seed, sz, tr, finished, served)
+    readings = check_served(model, seed, sz, tr, finished, served)
     ref_s = time.perf_counter() - t_ref
 
     result = {"correct": None, "attempted": len(reqs),
@@ -219,7 +218,7 @@ def run(cell, args, t_start: float, devices, tracer: harness.Tracer):
                        "unit": "ms"},
         "setup_s": {"value": setup_s, "unit": "s"},
     }
-    ctx = {"kind": "serve", "sz": sz, "traffic": tr, "chips": len(devices),
+    ctx = {"kind": "serve", "model": model, "sz": sz, "traffic": tr, "chips": len(devices),
            "device_kind": devices[0].device_kind, "window_s": window_s,
            "trace": tracer.summary, "roofline_bound_s": bound_s,
            "step_s": rec.step_s, "prefill_s": rec.prefill_s}
@@ -251,13 +250,6 @@ def check_sample(seed, tr, finished, served) -> List:
     return [longest] + picks
 
 
-@functools.partial(jax.jit, static_argnums=0)
-def _position_logits(sz, top, h, pos):
-    """Logits (N, P, V) of hidden states h (N, T, D) at positions pos (N, P)."""
-    hp = jnp.take_along_axis(h, pos[..., None], axis=1)
-    return hp @ dense.head_table(sz, top).T
-
-
 @jax.jit
 def _gaps(logits, toks):
     """Best logit minus the logit of ``toks``, per position."""
@@ -265,8 +257,9 @@ def _gaps(logits, toks):
     return best - jnp.take_along_axis(logits, toks[..., None], axis=-1)[..., 0]
 
 
-def reference_gaps(seed, sz, tr, sample, served, quant=None, block: int = 4):
-    """Per request: the gaps of its served tokens in the reference's logits.
+def reference_gaps(model, seed, sz, tr, sample, served, quant=None, block: int = 4):
+    """Per request: the gaps of its served tokens in the logits of the
+    reference of family module ``model``.
 
     Returns, per sampled request, (gap of each served token, gap of the
     token the ``quant`` pass ranks first, or None). A gap is the
@@ -278,8 +271,8 @@ def reference_gaps(seed, sz, tr, sample, served, quant=None, block: int = 4):
     n_pos = tr["output_len"]["max"]
     out = []
     with jax.default_matmul_precision("highest"):
-        ref = dense.Reference(seed, sz)
-        low = dense.Reference(seed, sz, quant) if quant else None
+        ref = model.Reference(seed, sz)
+        low = model.Reference(seed, sz, quant) if quant else None
         for i in range(0, len(sample), block):
             part = sample[i:i + block]
             rows = part + [part[0]] * (block - len(part))
@@ -292,13 +285,12 @@ def reference_gaps(seed, sz, tr, sample, served, quant=None, block: int = 4):
                 toks[j, :len(seq)] = seq
                 pos[j, :len(got)] = len(r.prompt) - 1 + np.arange(len(got))
                 want[j, :len(got)] = got
-            adps = [dense.adapter_set(seed, sz, r.tenant) for r in rows]
+            adps = [common.adapter_set(seed, sz, r.tenant) for r in rows]
             stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *adps)
             tk, ps, wt = jnp.asarray(toks[:, None]), jnp.asarray(pos), jnp.asarray(want)
 
-            def logits_of(model):
-                x0 = dense._embed_fwd(sz, model.top, stacked, tk, None)
-                return _position_logits(sz, model.top, model.hidden(x0), ps)
+            def logits_of(r):
+                return r.logits_at(r.hidden(r.embed(stacked, tk, None)), ps)
 
             ref_lg = logits_of(ref)
             gap = np.asarray(_gaps(ref_lg, wt))
@@ -312,9 +304,9 @@ def reference_gaps(seed, sz, tr, sample, served, quant=None, block: int = 4):
     return out
 
 
-def check_served(seed, sz, tr, finished, served) -> Dict[str, float]:
+def check_served(model, seed, sz, tr, finished, served) -> Dict[str, float]:
     sample = check_sample(seed, tr, finished, served)
     if not sample:
         return {"logit_gap": math.inf}
-    gaps = reference_gaps(seed, sz, tr, sample, served)
+    gaps = reference_gaps(model, seed, sz, tr, sample, served)
     return {"logit_gap": float(max(np.max(g) for g, _ in gaps))}

@@ -5,16 +5,17 @@ layer and pass; its custom VJP's backward is plain XLA operations that the
 trace cannot tell from the rest of the layer, so it counts on neither side.
 Every launch in the traced window counts on both sides: its device time,
 and its least time, the larger of its FLOPs over the bf16 peak and its
-bytes over HBM bandwidth (``bench.flops.flash_cost``) at the batch and
-heads of its output shape and the cell's real sequence length (the kernel
-pads it to its block). Under remat the forward runs twice per pass, and
-both launches count. A launch is a custom call that the trace names after
-the kernel's launcher, ``_fa_jit``.
+bytes over HBM bandwidth, as the configuration's family module counts the
+launch (``flash_launch_cost``: at the batch and heads of its output shape
+and the cell's real sequence length, which the kernel pads to its block).
+So the roofline counts the same work whatever layer launches the kernel.
+Under remat the forward runs twice per pass, and both launches count. A
+launch is a custom call that the trace names after the kernel's launcher,
+``_fa_jit``.
 """
-import math
 import re
 
-from bench import flops, peaks
+from bench import peaks
 
 LAUNCHER = "_fa_jit"
 OUT_DIMS = re.compile(r"=\s*\(?\s*\w+\[([\d,]+)\]")
@@ -40,13 +41,11 @@ def read(ctx):
     t = sum(sec for sec, _ in calls)
     if t <= 0:
         return None
-    sz, tr = ctx["sz"], ctx["traffic"]
+    model, sz, tr = ctx["model"], ctx["sz"], ctx["traffic"]
     seq = tr["text_len"] + (sz.image_patches if sz.frontend else 0)
     pk = peaks.peaks(ctx["device_kind"])
     least = 0.0
     for _, dims in calls:
-        # the output is head-major: (batch..., heads, positions, head_dim)
-        f, b = flops.flash_cost(math.prod(dims[:-3]), dims[-3], sz.kv_heads, seq, seq,
-                                sz.head_dim, backward=False)
+        f, b = model.flash_launch_cost(sz, dims, seq)
         least += max(f / pk["bf16_flops_per_s"], b / pk["hbm_bytes_per_s"])
     return 100.0 * least / t

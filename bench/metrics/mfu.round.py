@@ -1,8 +1,9 @@
 """mfu.round: the round's needed FLOPs per second over the chips' bf16 peak.
 
-Needed FLOPs are counted from shapes by ``bench.flops.round_flops`` (frozen
-backbone: input gradients only, causal attention, the head where the loss
-reads); the rate is over the whole traced window on the host clock.
+Needed FLOPs are counted from shapes by the family module's
+``round_flops`` (the rules of ``bench/flops.py``: frozen backbone, input
+gradients only, causal attention, the head where the loss reads); the rate
+is over the whole traced window on the host clock.
 """
 from bench import peaks
 

@@ -2,9 +2,9 @@
 
 For every prefill and decode step of the window, the least time the chip
 needs is the larger of its needed FLOPs over the bf16 peak and its needed
-bytes over HBM bandwidth (``bench.flops.prefill_cost``/``decode_cost``:
-real prompt positions, live slots' KV up to their positions). Their sum
-over the window's length.
+bytes over HBM bandwidth (the family module's ``prefill_cost`` and
+``decode_cost``: real prompt positions, live slots' KV up to their
+positions). Their sum over the window's length.
 """
 
 

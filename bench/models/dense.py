@@ -1,4 +1,4 @@
-"""Dense decoders of the qwen2 family: weights from the seed and the plain reference.
+"""Family ``dense``: decoders of the qwen2 family, their weights, reference and counts.
 
 The architecture (Qwen2 / Qwen2-VL language model, arXiv:2407.10671 and
 arXiv:2409.12191): pre-norm blocks ``x += attn(rms(x)); x += mlp(rms(x))``;
@@ -7,9 +7,11 @@ grouped-query attention with biases on q, k and v, rotary positions
 that read three position components); SwiGLU MLP; a final RMSNorm and an
 untied head. FedNano's NanoAdapters (rank r, scale alpha / r) sit at the
 connector-to-LLM interface: ``y = x + scale (x down) up`` on token
-embeddings and on connected image patches, which are prepended to the text.
+embeddings and on connected image patches, which are prepended to the text
+(``bench/models/common.py``).
 
-Two things live here, and neither imports the program:
+It implements the contract of a family module (``bench/models/__init__.py``).
+Three things live here, and none imports the program:
 
 * ``backbone_weights``: the frozen weights in the layout the program's
   backbone takes, made on the device in one jitted call from the seed, in
@@ -21,6 +23,9 @@ Two things live here, and neither imports the program:
   kept per layer), with the input gradients taken layer by layer backwards.
   ``quant="fp8"`` rounds every weight matrix to float8 e4m3 per output
   channel first: the control that a lower precision must fail.
+* the counts: the FLOPs and bytes a round, a prefill, a decode step and a
+  flash-attention launch need, built from the dense layer's shapes and the
+  family-independent terms of ``bench/flops.py``.
 
 Sizes come from the configuration file's ``run`` section (Hugging Face
 names), never from the program's config object.
@@ -29,12 +34,15 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import zlib
-from typing import Dict, List, Optional, Sequence, Tuple
+import math
+from typing import Dict, Iterable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import flops
+from bench.models.common import adapt, path_key, seed_key
 
 RMS_EPS_DEFAULT = 1e-6
 
@@ -86,16 +94,6 @@ def sizes(config: Dict) -> Sizes:
 # ---------------------------------------------------------------------------
 # weights from the seed
 # ---------------------------------------------------------------------------
-
-def seed_key(seed: int):
-    """A PRNG key from any non-negative whole number (more than 32 bits)."""
-    k = jax.random.PRNGKey(seed & 0xFFFFFFFF)
-    return jax.random.fold_in(k, (seed >> 32) & 0x7FFFFFFF)
-
-
-def _path_key(key, path: str):
-    return jax.random.fold_in(key, zlib.crc32(path.encode()) & 0x7FFFFFFF)
-
 
 def _top_leaves(sz: Sizes) -> List[Tuple[str, Tuple[int, ...], str]]:
     out = [("embed/table", (sz.vocab, sz.d), "table"),
@@ -156,9 +154,9 @@ def _backbone(key, sz: Sizes, dtype_name: str):
     dtype = jnp.dtype(dtype_name)
     flat = {}
     for path, shape, kind in _top_leaves(sz):
-        flat[path] = _draw(kind, _path_key(key, path), shape).astype(dtype)
+        flat[path] = _draw(kind, path_key(key, path), shape).astype(dtype)
     for path, shape, kind in _layer_leaves(sz):
-        k = _path_key(key, path)
+        k = path_key(key, path)
         # one layer at a time: a layer's float32 draw is the only temporary
         flat[path] = jax.lax.map(
             lambda i, k=k, kind=kind, shape=shape:
@@ -177,17 +175,10 @@ def backbone_weights(seed: int, sz: Sizes, dtype: str = "bfloat16",
     return fn(seed_key(seed), sz, dtype)
 
 
-def adapter_set(seed: int, sz: Sizes, tag: str) -> Dict:
-    """A trained-looking NanoAdapter set (``up`` != 0), float32, named ``tag``."""
-    key = _path_key(seed_key(seed), "adapters/" + tag)
-    out = {}
-    for j, mod in enumerate(sz.modalities):
-        kd, ku = jax.random.split(jax.random.fold_in(key, j))
-        out[mod] = {
-            "down": jax.random.normal(kd, (sz.d, sz.rank)) * sz.d ** -0.5,
-            "up": jax.random.normal(ku, (sz.rank, sz.d)) * 0.05,
-        }
-    return out
+def backbone_shapes(sz: Sizes, dtype: str = "bfloat16"):
+    """The backbone's leaves as ``jax.ShapeDtypeStruct``s; nothing is made."""
+    return jax.eval_shape(lambda key: _backbone.__wrapped__(key, sz, dtype),
+                          jax.random.PRNGKey(0))
 
 
 # ---------------------------------------------------------------------------
@@ -211,7 +202,7 @@ QUANT = {"fp8": _quant_fp8}
 
 
 def _ref_leaf(key, path, shape, kind, layer, quant):
-    k = _path_key(key, path)
+    k = path_key(key, path)
     if layer is not None:
         k = jax.random.fold_in(k, layer)
     # the served bf16 values, exactly: a round trip through a bf16 cast may
@@ -298,11 +289,6 @@ def layer_forward(sz: Sizes, p: Dict, x, ang):
     return x + (jax.nn.silu(h @ m["w_gate"]) * (h @ m["w_up"])) @ m["w_down"]
 
 
-def adapt(sz: Sizes, adp: Dict, x):
-    """NanoAdapter residual, float32."""
-    return x + sz.scale * (x @ adp["down"]) @ adp["up"]
-
-
 def embed(sz: Sizes, top: Dict, adapters: Dict, tokens, patches):
     """Backbone-ready embeddings of one client's rows (image prefix first)."""
     x = jnp.take(top["embed"]["table"], tokens, axis=0)
@@ -345,6 +331,13 @@ _embed_fwd = jax.jit(_embed_clients, static_argnums=0)
 def _embed_bwd(sz: Sizes, top, adps, tokens, patches, g):
     _, vjp = jax.vjp(lambda a: _embed_clients(sz, top, a, tokens, patches), adps)
     return vjp(g)[0]
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _position_logits(sz: Sizes, top, h, pos):
+    """Logits (N, P, V) of hidden states h (N, T, D) at positions pos (N, P)."""
+    hp = jnp.take_along_axis(h, pos[..., None], axis=1)
+    return hp @ head_table(sz, top).T
 
 
 @functools.partial(jax.jit, static_argnums=(0, 8))
@@ -407,6 +400,16 @@ class Reference:
             x = _layer_fwd(self.sz, self.layer(i), x, ang)
         return rmsnorm(x, self.top["final_norm"]["scale"], self.sz.rms_eps)
 
+    def embed(self, adapters_k: Dict, tokens, patches):
+        """(K*B, S, D) embeddings of K clients' (K, B, ...) rows, each client
+        under its own adapters (trees stacked over K)."""
+        return _embed_fwd(self.sz, self.top, adapters_k, tokens, patches)
+
+    def logits_at(self, h, pos):
+        """Logits (N, P, V) of final-normed hidden states h (N, T, D) at
+        positions pos (N, P)."""
+        return _position_logits(self.sz, self.top, h, pos)
+
     def loss_and_grads(self, adapters_k: Dict, tokens, labels, mask, patches):
         """Per-client mean masked cross-entropy and its adapter gradients.
 
@@ -418,7 +421,7 @@ class Reference:
         kk = tokens.shape[0]
         tokens = jnp.asarray(tokens)
         patches = None if patches is None else jnp.asarray(patches)
-        x0 = _embed_fwd(sz, top, adapters_k, tokens, patches)
+        x0 = self.embed(adapters_k, tokens, patches)
         ang = rope_angles(sz, x0.shape[1])
         xs = [x0]
         for i in range(sz.layers):
@@ -430,35 +433,79 @@ class Reference:
         return losses, _embed_bwd(sz, top, adapters_k, tokens, patches, g)
 
 
-@functools.partial(jax.jit, static_argnames=("lr", "grad_clip"))
-def adamw_step(g, m, v, p, step, *, lr: float, grad_clip: float,
-               b1=0.9, b2=0.999, eps=1e-8):
-    """Decoupled AdamW (no weight decay) with global-norm clipping, per
-    client: every tree is stacked over clients on its leading axis, and
-    ``step`` (K,) is each client's count of steps, this one included."""
-    def one(g, m, v, p, step):
-        if grad_clip:
-            norm = jnp.sqrt(sum(jnp.sum(jnp.square(x)) for x in jax.tree.leaves(g)))
-            g = jax.tree.map(lambda x: x * jnp.minimum(1.0, grad_clip / (norm + 1e-9)), g)
-        m = jax.tree.map(lambda a, x: b1 * a + (1 - b1) * x, m, g)
-        v = jax.tree.map(lambda a, x: b2 * a + (1 - b2) * x * x, v, g)
-        step = step.astype(jnp.float32)
-        c1, c2 = 1 - b1 ** step, 1 - b2 ** step
-        p = jax.tree.map(lambda w, a, s: w - lr * ((a / c1) / (jnp.sqrt(s / c2) + eps)),
-                         p, m, v)
-        return p, m, v
+# ---------------------------------------------------------------------------
+# the counts (rules in bench/flops.py)
+# ---------------------------------------------------------------------------
 
-    return jax.vmap(one)(g, m, v, p, step)
+def layer_matmul_params(sz: Sizes) -> int:
+    q, kv = sz.heads * sz.head_dim, sz.kv_heads * sz.head_dim
+    return sz.d * q + 2 * sz.d * kv + q * sz.d + 3 * sz.d * sz.ff
 
 
-def fisher_merge(thetas: Sequence[Dict], fishers: Sequence[Dict],
-                 sizes_: Sequence[float], eps: float = 1e-8) -> Dict:
-    """FedNano's Eq. 1: sum_k p_k F_k theta_k / (sum_k p_k F_k + eps)."""
-    w = np.asarray(sizes_, np.float64)
-    w = w / w.sum()
-    num = jax.tree.map(lambda *ts: sum(float(wk) * t for wk, t in zip(w, ts)),
-                       *[jax.tree.map(lambda t, f: f * t, th, fi)
-                         for th, fi in zip(thetas, fishers)])
-    den = jax.tree.map(lambda *fs: sum(float(wk) * f for wk, f in zip(w, fs)),
-                       *fishers)
-    return jax.tree.map(lambda n, d: n / (d + eps), num, den)
+def layer_param_bytes(sz: Sizes, dtype_bytes: int = flops.BF16) -> int:
+    q, kv = sz.heads * sz.head_dim, sz.kv_heads * sz.head_dim
+    return (layer_matmul_params(sz) + q + 2 * kv + 2 * sz.d) * dtype_bytes
+
+
+def weight_bytes(sz: Sizes, dtype_bytes: int = flops.BF16) -> int:
+    """Frozen weights a decode step or prefill reads once: the layers, the
+    head table and the final norm (embedding lookups read single rows)."""
+    return (sz.layers * layer_param_bytes(sz, dtype_bytes)
+            + flops.head_bytes(sz, dtype_bytes))
+
+
+def attention_flops(sz: Sizes, s: int, backward: bool) -> float:
+    """Causal self-attention scores and mixing, one sequence, all layers."""
+    per = 4 * sz.heads * sz.head_dim * s * s / 2
+    return sz.layers * per * (3 if backward else 1)
+
+
+def round_flops(sz: Sizes, *, sequences: int, text_len: int, image_len: int,
+                loss_positions: int) -> float:
+    """FLOPs a federated round needs for ``sequences`` forward+backward passes.
+
+    ``loss_positions`` is the total, over those sequences, of positions
+    whose label the loss reads.
+    """
+    s = text_len + image_len
+    n = sz.layers * layer_matmul_params(sz)
+    backbone = 4 * n * s + attention_flops(sz, s, backward=True)
+    adapters = flops.adapter_flops(sz, flops.adapted_positions(sz, text_len, image_len),
+                                   backward=True)
+    return (sequences * (backbone + adapters + flops.connector_flops(sz, image_len))
+            + flops.head_flops(sz, loss_positions, backward=True))
+
+
+def kv_bytes_per_position(sz: Sizes, dtype_bytes: int = flops.BF16) -> int:
+    return sz.layers * 2 * sz.kv_heads * sz.head_dim * dtype_bytes
+
+
+def prefill_cost(sz: Sizes, length: int):
+    """(FLOPs, bytes) of one batch-1 prefill of ``length`` real positions."""
+    n = sz.layers * layer_matmul_params(sz)
+    need = (2 * n * length + attention_flops(sz, length, backward=False)
+            + flops.head_flops(sz, 1) + flops.adapter_flops(sz, length))
+    return need, weight_bytes(sz) + kv_bytes_per_position(sz) * length
+
+
+def decode_cost(sz: Sizes, positions: Iterable[int]):
+    """(FLOPs, bytes) of one decode step over the live slots' positions.
+
+    A slot at position p attends p + 1 keys (its history and itself).
+    """
+    n = sz.layers * layer_matmul_params(sz)
+    need = bytes_ = 0.0
+    for p in positions:
+        need += (2 * n + sz.layers * 4 * sz.heads * sz.head_dim * (p + 1)
+                 + flops.head_flops(sz, 1) + flops.adapter_flops(sz, 1))
+        bytes_ += kv_bytes_per_position(sz) * (p + 1)
+    return need, weight_bytes(sz) + bytes_
+
+
+def flash_launch_cost(sz: Sizes, out_dims, seq: int):
+    """(FLOPs, bytes) of one launch of the flash forward kernel whose output
+    has dims ``out_dims``, over ``seq`` real positions (the kernel pads
+    them to its block)."""
+    # the output is head-major: (batch..., heads, positions, head_dim)
+    return flops.flash_cost(math.prod(out_dims[:-3]), out_dims[-3], sz.kv_heads,
+                            seq, seq, sz.head_dim, backward=False)

@@ -34,14 +34,13 @@ def main(argv=None):
 
     from bench import harness, spec, traffic_gen
     from bench.kinds import serve
-    from bench.models import dense
 
     enable_compile_cache()
     cell = spec.load_cell(args.workload)
     if jax.devices()[0].platform != "tpu":
         print("sweep: no TPU visible", file=sys.stderr)
         return 1
-    sz = dense.sizes(cell.config)
+    sz = cell.model.sizes(cell.config)
     engine = serve.build(cell, args.seed)
     null = harness.Tracer(False)
     for i, rate in enumerate(float(x) for x in args.rates.split(",")):

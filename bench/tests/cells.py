@@ -11,9 +11,10 @@ the cells' limits are set on the chip: above what the sound program reads
 Readings (three seeds; program max / control min, and the half-batch
 fault's min where the control does not read three times the program):
 silo-vqa loss 6.1e-5 / 9.2e-5 (half 4.6e-3), global_delta 0.0012 /
-0.0067; xdevice loss 1.6e-4 / 7.6e-4, global_delta 0.00082 / 0.0013
-(half 0.17); merge_last at most 2.6e-7 on both, where a merged update
-applied twice reads 1; chat-zipf logit_gap 0.0047 / 0.035.
+0.0067, fisher 0.012 / 0.058 (half 1.7); xdevice loss 1.6e-4 /
+7.6e-4, global_delta 0.00082 / 0.0013 (half 0.17); merge_last at most
+2.6e-7 on both, where a merged update applied twice reads 1; chat-zipf
+logit_gap 0.0047 / 0.035.
 """
 from __future__ import annotations
 
@@ -54,7 +55,7 @@ def _tiny_config(name: str):
 
 TINY_LIMITS = {
     "round.qwen2-vl-72b.silo-vqa": {"loss": 3e-4, "global_delta": 0.003,
-                                    "merge_last": 1e-5},
+                                    "merge_last": 1e-5, "fisher": 0.03},
     "round.qwen1.5-4b.xdevice": {"loss": 4e-4, "global_delta": 0.004,
                                  "merge_last": 1e-5},
     "serve.qwen1.5-4b.chat-zipf": {"logit_gap": 0.02},

@@ -98,3 +98,31 @@ def test_round_faults_are_caught(name, fault, monkeypatch):
         _answer_altered(monkeypatch)
     result, checks = run_tiny(cell, SEED)
     assert not result["correct"], checks
+
+
+def test_readings_take_the_worst_leaf_and_client():
+    import numpy as np
+
+    from bench.kinds import round as rk
+
+    def tree(vals):
+        names = [("text", "down"), ("text", "up"), ("image", "down"), ("image", "up")]
+        out = {}
+        for (mod, leaf), v in zip(names, vals):
+            out.setdefault(mod, {})[leaf] = np.array([v])
+        return out
+
+    keep = ["text.down", "text.up", "image.down", "image.up"]
+    zero = tree([0.0] * 4)
+    ref = {"loss": [10.0, 8.0], "start": [zero, zero],
+           "global": [tree([1.0] * 4), tree([1.0] * 4)], "keep": keep}
+    got = {"loss": [10.05, 8.0], "start": [zero, zero],
+           "global": [tree([1.01, 1.002, 1.003, 1.0]), tree([1.02, 1.0, 1.0, 1.0])]}
+    r = rk.compare(got, ref)
+    assert r["loss"] == pytest.approx(0.005)
+    assert r["global_delta"] == pytest.approx(0.02)
+    # two clients: gaps 0.01, 0.002, 0.003, 0 and 0.02, 0.004, 0.001, 0.001
+    prog = [tree([1.01, 1.002, 1.003, 1.0]), tree([2.04, 2.008, 1.998, 2.002])]
+    want = [tree([1.0] * 4), tree([2.0] * 4)]
+    assert rk.fisher_gap(prog, want, keep) == pytest.approx(0.02)
+    assert rk.fisher_gap(want, want, keep) == 0.0

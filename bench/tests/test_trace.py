@@ -77,13 +77,13 @@ def test_flash_roofline_counts_each_launch_of_the_kernel():
     import dataclasses
 
     from bench import spec
-    from bench.models import dense
 
     s = trace.reduce(trace.read_events(DATA))
     read = spec.metric_reader("flash_attention_roofline")
     cell = spec.load_cell("round.qwen2-vl-72b.silo-vqa")
-    ctx = {"kind": "round", "trace": s, "sz": dense.sizes(cell.config),
-           "traffic": cell.traffic, "device_kind": "TPU v5 lite", "chips": 1}
+    ctx = {"kind": "round", "trace": s, "model": cell.model,
+           "sz": cell.model.sizes(cell.config), "traffic": cell.traffic,
+           "device_kind": "TPU v5 lite", "chips": 1}
     # one launch in the excerpt: output bf16[5,2,64,384,128] (5 clients x 2
     # rows, 64 heads, 320 positions padded to 384), 1317.66875 us. Bytes
     # bound it: q and o 2 x 10*64*320*128*2, k and v 2 x 10*8*320*128*2,
