@@ -13,6 +13,7 @@ from repro.configs.base import (
     AdapterConfig,
     InputShape,
     ModelConfig,
+    MLAConfig,
     MoEConfig,
     RGLRUConfig,
     SSMConfig,
@@ -24,6 +25,7 @@ from repro.configs import (  # noqa: E402
     grok_1_314b,
     h2o_danube_1_8b,
     internlm2_20b,
+    kimi_vl_a3b,
     llama4_scout_17b_a16e,
     llava15_7b,
     mamba2_130m,
@@ -45,6 +47,7 @@ _REGISTRY: Dict[str, Callable[[], ModelConfig]] = {
     "glm4-9b": glm4_9b.config,
     "whisper-base": whisper_base.config,
     "internlm2-20b": internlm2_20b.config,
+    "kimi-vl-a3b": kimi_vl_a3b.config,
     # the paper's own backbones
     "llava-1.5-7b": llava15_7b.config,
     "minigpt4-7b": minigpt4_7b.config,
@@ -82,6 +85,7 @@ def get_smoke_config(arch: str, **overrides) -> ModelConfig:
 
 __all__ = [
     "ModelConfig",
+    "MLAConfig",
     "MoEConfig",
     "SSMConfig",
     "RGLRUConfig",

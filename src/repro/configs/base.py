@@ -16,11 +16,51 @@ from typing import Optional, Tuple
 
 @dataclass(frozen=True)
 class MoEConfig:
+    """A dropless expert layer, of which this chip may hold a share.
+
+    The router scores all ``n_experts``: ``softmax`` over them, or
+    ``sigmoid`` of each, to whose scores ``score_bias`` adds a learned
+    correction bias for the choice of the ``top_k`` only (DeepSeek-V3's
+    ``noaux_tc``). A chosen expert's weight is its score, renormalised over
+    the chosen ones, times ``routed_scale``. The chip holds experts
+    ``[expert_first, expert_first + experts_held)`` (``experts_held`` 0:
+    all of them) and computes their part of the result; the absent
+    experts' part is left out.
+    """
+
     n_experts: int = 8
     top_k: int = 2
-    capacity_factor: float = 1.25
     router_aux_weight: float = 0.01  # load-balance loss weight (frozen backbone -> reported only)
-    shared_d_ff: int = 0  # llama4-style shared expert FFN width (0 = none)
+    shared_d_ff: int = 0  # width of the shared experts, one SwiGLU for every token (0 = none)
+    scoring: str = "softmax"  # softmax | sigmoid
+    score_bias: bool = False
+    routed_scale: float = 1.0
+    expert_first: int = 0
+    experts_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.n_experts
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434), no query LoRA.
+
+    q = x W_q splits per head into ``qk_nope_head_dim`` + ``qk_rope_head_dim``;
+    [c_kv; k_rope] = x W_kva, c_kv RMS-normed, k_rope rotated and shared by
+    all heads; [k_nope; v] = c_kv W_kvb per head. Scores read the q/k width
+    ``qk_nope_head_dim + qk_rope_head_dim``, values ``v_head_dim``.
+    """
+
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclass(frozen=True)
@@ -80,12 +120,16 @@ class ModelConfig:
 
     # block structure
     norm: str = "rmsnorm"          # rmsnorm | layernorm
+    norm_eps: float = 1e-6         # RMSNorm epsilon
     act: str = "swiglu"            # swiglu | geglu | gelu
     tie_embeddings: bool = False
     parallel_block: bool = False   # parallel attn+ffn residual (grok-style off; kept for ext.)
 
     # sub-family configs
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None         # latent attention in place of per-head K/V
+    n_dense_layers: int = 0        # leading dense layers before the scanned MoE stack
+    dense_d_ff: int = 0            # their MLP width (0 -> d_ff)
     ssm: Optional[SSMConfig] = None
     rglru: Optional[RGLRUConfig] = None
 
@@ -129,6 +173,12 @@ class ModelConfig:
         return self.n_heads // max(self.n_kv_heads, 1)
 
     def with_(self, **kw) -> "ModelConfig":
+        """A copy with ``kw`` replaced; a dict given for a sub-config (``moe``,
+        ``mla``, ...) replaces those fields of it."""
+        for k, v in kw.items():
+            cur = getattr(self, k)
+            if isinstance(v, dict) and dataclasses.is_dataclass(cur):
+                kw[k] = replace(cur, **v)
         return replace(self, **kw)
 
 
@@ -161,10 +211,20 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         adapter=dataclasses.replace(cfg.adapter, rank=4, alpha=8.0),
     )
     if cfg.moe is not None:
+        n_e = min(cfg.moe.n_experts, 4)
+        held = min(cfg.moe.experts_held, n_e // 2) if cfg.moe.experts_held else 0
         kw["moe"] = dataclasses.replace(
-            cfg.moe, n_experts=min(cfg.moe.n_experts, 4), top_k=min(cfg.moe.top_k, 2),
+            cfg.moe, n_experts=n_e, top_k=min(cfg.moe.top_k, 2),
             shared_d_ff=min(cfg.moe.shared_d_ff, 256) if cfg.moe.shared_d_ff else 0,
+            expert_first=min(cfg.moe.expert_first, n_e - max(held, 1)), experts_held=held,
         )
+    if cfg.mla is not None:
+        kw["mla"] = dataclasses.replace(cfg.mla, kv_lora_rank=32, qk_nope_head_dim=16,
+                                        qk_rope_head_dim=8, v_head_dim=16)
+    if cfg.n_dense_layers:
+        kw["n_dense_layers"] = 1
+        kw["n_layers"] = min(cfg.n_layers, 3)   # the dense layer and two MoE layers
+        kw["dense_d_ff"] = min(cfg.dense_d_ff, 512)
     if cfg.ssm is not None:
         kw["ssm"] = dataclasses.replace(cfg.ssm, d_state=16, head_dim=32, chunk_size=32)
     if cfg.rglru is not None:

@@ -27,6 +27,6 @@ def config() -> ModelConfig:
         logit_softcap=30.0,
         norm="rmsnorm",
         act="gelu",
-        moe=MoEConfig(n_experts=8, top_k=2, capacity_factor=1.25),
+        moe=MoEConfig(n_experts=8, top_k=2),
         adapter=AdapterConfig(rank=64, alpha=128.0, modalities=("text",)),
     )

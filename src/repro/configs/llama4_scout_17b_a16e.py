@@ -22,6 +22,6 @@ def config() -> ModelConfig:
         rope_theta=500000.0,
         norm="rmsnorm",
         act="swiglu",
-        moe=MoEConfig(n_experts=16, top_k=1, capacity_factor=1.25, shared_d_ff=8192),
+        moe=MoEConfig(n_experts=16, top_k=1, shared_d_ff=8192),
         adapter=AdapterConfig(rank=64, alpha=128.0, modalities=("text",)),
     )

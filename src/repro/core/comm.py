@@ -85,6 +85,11 @@ def backbone_param_count(cfg) -> int:
     attn = d * cfg.n_heads * hd + 2 * d * cfg.n_kv_heads * hd + cfg.n_heads * hd * d
     if cfg.qkv_bias:
         attn += cfg.n_heads * hd + 2 * cfg.n_kv_heads * hd
+    if cfg.mla is not None:
+        m = cfg.mla
+        attn = (d * cfg.n_heads * m.qk_head_dim + d * (m.kv_lora_rank + m.qk_rope_head_dim)
+                + m.kv_lora_rank * (1 + cfg.n_heads * (m.qk_nope_head_dim + m.v_head_dim))
+                + cfg.n_heads * m.v_head_dim * d)
     if cfg.act in ("swiglu", "geglu"):
         mlp = 3 * d * f
     else:
@@ -96,8 +101,10 @@ def backbone_param_count(cfg) -> int:
         m = cfg.moe
         experts = m.n_experts * 3 * d * f
         shared = 3 * d * m.shared_d_ff if m.shared_d_ff else 0
-        router = d * m.n_experts
-        total += L * (attn + experts + shared + router + norms)
+        router = d * m.n_experts + (m.n_experts if m.score_bias else 0)
+        lead = cfg.n_dense_layers
+        total += (L - lead) * (attn + experts + shared + router + norms)
+        total += lead * (attn + 3 * d * (cfg.dense_d_ff or f) + norms)
     elif cfg.family == "ssm":
         s = cfg.ssm
         d_inner = s.expand * d

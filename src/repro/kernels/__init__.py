@@ -11,9 +11,11 @@ Each kernel ships three files (per the repo convention):
 Kernels:
     lora/            fused NanoAdapter residual  y = x + s·(x·A)·B
     fisher_merge/    Eq.-1 K-client Fisher-weighted merge (memory-bound)
-    flash_attention/ blockwise online-softmax attention (GQA/SWA/softcap)
+    flash_attention/ blockwise online-softmax attention (GQA/SWA/softcap,
+                     a v width of its own for latent attention)
+    moe_gmm/         grouped matmul over the experts a chip holds (dropless)
     ssd_scan/        Mamba2 chunked SSD scan (state carried in VMEM scratch)
 """
-from repro.kernels import fisher_merge, flash_attention, lora, ssd_scan
+from repro.kernels import fisher_merge, flash_attention, lora, moe_gmm, ssd_scan
 
-__all__ = ["fisher_merge", "flash_attention", "lora", "ssd_scan"]
+__all__ = ["fisher_merge", "flash_attention", "lora", "moe_gmm", "ssd_scan"]

@@ -18,6 +18,8 @@ TPU-native online-softmax attention (the SDPA replacement, DESIGN.md §3):
     two dims of every block are (block, D) or (block, 1) — the TPU's tiling
     rule (divisible by (8, 128) or equal to the array dims). The public
     wrapper keeps the model's (B, S, H, D) layout and transposes.
+  * v (and so the output) may have a head width Dv of its own: latent
+    attention scores at the q/k width and mixes values of another.
 
 Numerics: all softmax state in fp32 scratch regardless of input dtype.
 """
@@ -47,7 +49,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
 
     q = q_ref[0, 0].astype(jnp.float32)          # (bq, D)
     k = k_ref[0, 0].astype(jnp.float32)          # (bk, D)
-    v = v_ref[0, 0].astype(jnp.float32)          # (bk, D)
+    v = v_ref[0, 0].astype(jnp.float32)          # (bk, Dv)
 
     # q·kᵀ contracting the head dim of both (no explicit transpose)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -101,7 +103,8 @@ def flash_attention(
     interpret: bool = False,
     return_lse: bool = False,
 ):
-    """q (B, Sq, H, D); k, v (B, Sk, Hkv, D), H % Hkv == 0. Returns (B, Sq, H, D).
+    """q, k (B, S, H or Hkv, D); v (B, Sk, Hkv, Dv), H % Hkv == 0. Returns
+    (B, Sq, H, Dv).
 
     Query i has absolute position (Sk - Sq) + i (decode/prefill alignment).
     With ``return_lse`` also returns the per-row logsumexp (B, H, Sq) — the
@@ -109,7 +112,8 @@ def flash_attention(
     """
     B, Sq, H, D = q.shape
     Bk, Sk, Hkv, Dk = k.shape
-    assert (B, D) == (Bk, Dk) and H % Hkv == 0
+    Dv = v.shape[-1]
+    assert (B, D) == (Bk, Dk) and v.shape[:3] == k.shape[:3] and H % Hkv == 0
     group = H // Hkv
 
     bq = min(block_q, Sq)
@@ -141,20 +145,20 @@ def flash_attention(
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j, g=group: (b, h // g, j, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j, g=group: (b, h // g, j, 0)),
+            pl.BlockSpec((1, 1, bk, Dv), lambda b, h, i, j, g=group: (b, h // g, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, Dv), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Sqp, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Sqp, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, Sqp, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)

@@ -10,7 +10,8 @@ from p = exp(s − L) instead of differentiating through a softmax —
     dq = scale · ds·k,   dk = scale · dsᵀ·q
 
 with the softcap chain factor (1 − tanh²) folded into ds and GQA K/V grads
-summed over each head group. This is an independent implementation of the
+summed over each head group. v may be narrower or wider than q and k
+(latent attention): the scale is the q/k width's, dv has v's width. This is an independent implementation of the
 gradient (saved-LSE + D-trick vs autodiff-through-softmax), so the parity
 check against ``jax.grad`` of the jnp ref in tests/kernel_harness.py is a
 real differential test of both the kernel's LSE and the backward math.
@@ -50,6 +51,7 @@ def _fa_bwd(causal, window, softcap, block_q, block_k, interpret, res, g):
     B, Sq, H, D = q.shape
     Hkv = k.shape[2]
     Sk = k.shape[1]
+    Dv = v.shape[-1]
     group = H // Hkv
 
     qf = q.astype(jnp.float32)
@@ -92,7 +94,7 @@ def _fa_bwd(causal, window, softcap, block_q, block_k, interpret, res, g):
 
     if group > 1:
         dk_h = dk_h.reshape(B, Sk, Hkv, group, D).sum(axis=3)
-        dv_h = dv_h.reshape(B, Sk, Hkv, group, D).sum(axis=3)
+        dv_h = dv_h.reshape(B, Sk, Hkv, group, Dv).sum(axis=3)
     return dq.astype(q.dtype), dk_h.astype(k.dtype), dv_h.astype(v.dtype)
 
 
@@ -110,14 +112,15 @@ def _fa_jit(q, k, v, *, causal, window, softcap, block_q, block_k, interpret):
 def flash_attention(q, k, v, *, causal=True, window: Optional[int] = None,
                     softcap: float = 0.0, block_q: int = None,
                     block_k: int = None, interpret: Optional[bool] = None):
-    """q (B, Sq, H, D); k, v (B, Sk, Hkv, D). Differentiable in (q, k, v).
+    """q (B, Sq, H, D); k (B, Sk, Hkv, D); v (B, Sk, Hkv, Dv). Returns
+    (B, Sq, H, Dv). Differentiable in (q, k, v).
 
     ``block_q``/``block_k`` = None → tuning table (clamped to the sequence
     lengths inside the kernel, so small shapes match the historical
     (128, 512) defaults exactly).
     """
     if block_q is None or block_k is None:
-        bq, bk = tuning.flash_blocks(q.shape[1], k.shape[1], q.shape[-1])
+        bq, bk = tuning.flash_blocks(q.shape[1], k.shape[1], q.shape[-1], v.shape[-1])
         block_q = bq if block_q is None else block_q
         block_k = bk if block_k is None else block_k
     return _fa_jit(q, k, v, causal=causal, window=window, softcap=softcap,

@@ -78,15 +78,29 @@ def lora_block_t(t: int, d: int, r: int) -> int:
     return min(_fit((64, 128, 256, 512), ws), max(t, 8))
 
 
-def flash_blocks(sq: int, sk: int, head_dim: int) -> Tuple[int, int]:
-    """(block_q, block_k) for flash attention. Clamped by the caller to the
-    actual sequence lengths, so small shapes reproduce the historical
-    (128, 512) behaviour exactly."""
-    cfg = PINNED.get(("flash_attention", _bucket(head_dim, (64, 128), "hd")))
+def flash_blocks(sq: int, sk: int, head_dim: int,
+                 v_head_dim: Optional[int] = None) -> Tuple[int, int]:
+    """(block_q, block_k) for flash attention with q/k width ``head_dim`` and
+    v width ``v_head_dim`` (default: the same), bucketed on the wider. Clamped
+    by the caller to the actual sequence lengths, so small shapes reproduce
+    the historical (128, 512) behaviour exactly."""
+    dv = v_head_dim or head_dim
+    cfg = PINNED.get(("flash_attention", _bucket(max(head_dim, dv), (64, 128), "hd")))
     if cfg:
         return cfg["block_q"], cfg["block_k"]
-    ws = lambda bk: (128 * head_dim * 2 + 2 * bk * head_dim + 128 * bk) * _F32
+    ws = lambda bk: (128 * (head_dim + dv) + bk * (head_dim + dv) + 128 * bk) * _F32
     return 128, _fit((128, 256, 512), ws)
+
+
+def gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
+    """(tm, tk, tn) of the grouped expert matmul: 512-row tiles (fewer where
+    m is smaller), k tiles up to 1024 and n tiles up to 512, so the bf16
+    operand tiles and the f32 accumulator stay near 3 MiB, double-buffered
+    well inside the VMEM budget. Row tiles are numerics-free; k tiles reorder
+    the f32 accumulation. A tile is the whole dimension where that is
+    smaller; the kernel masks ragged last k and n tiles."""
+    tm = min(512, max(8, -(-m // 8) * 8))
+    return tm, min(k, 1024), min(n, 512)
 
 
 def fisher_block_n(k: int, n: int) -> int:
@@ -115,7 +129,8 @@ def lookup(kernel: str, **dims) -> Dict[str, int]:
     if kernel == "lora":
         return {"block_t": lora_block_t(dims["t"], dims["d"], dims["r"])}
     if kernel == "flash_attention":
-        bq, bk = flash_blocks(dims["sq"], dims["sk"], dims["head_dim"])
+        bq, bk = flash_blocks(dims["sq"], dims["sk"], dims["head_dim"],
+                              dims.get("v_head_dim"))
         return {"block_q": bq, "block_k": bk}
     if kernel == "fisher_merge":
         return {"block_n": fisher_block_n(dims["k"], dims["n"])}

@@ -1,5 +1,5 @@
 """Attention: GQA / MHA, sliding-window, logit softcap, cross-attention,
-KV-cache decode.
+KV-cache decode, and multi-head latent attention (MLA) for train/prefill.
 
 Layout conventions:
     x           (B, S, D)
@@ -21,7 +21,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import dense_init
+from repro.models.layers import dense_init, init_rmsnorm, rmsnorm
 from repro.models.rotary import apply_rotary
 from repro.sharding import constrain, residual_spec
 
@@ -34,6 +34,8 @@ class KVCache(NamedTuple):
 
 
 def init_attention(key, cfg, cross: bool = False, dtype=jnp.float32):
+    if cfg.mla is not None:
+        return init_mla(key, cfg, dtype)
     d = cfg.d_model
     hd = cfg.resolved_head_dim
     nh, nkv = cfg.n_heads, cfg.n_kv_heads
@@ -49,6 +51,46 @@ def init_attention(key, cfg, cross: bool = False, dtype=jnp.float32):
         p["bk"] = jnp.zeros((nkv * hd,), dtype)
         p["bv"] = jnp.zeros((nkv * hd,), dtype)
     return p
+
+
+def init_mla(key, cfg, dtype=jnp.float32):
+    """Latent attention weights (no query LoRA): wq (D, H*(nope+rope)),
+    wkv_a (D, kv_lora+rope), kv_norm, wkv_b (kv_lora, H*(nope+v)),
+    wo (H*v, D)."""
+    m, d, nh = cfg.mla, cfg.d_model, cfg.n_heads
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(kq, (d, nh * m.qk_head_dim), dtype),
+        "wkv_a": dense_init(ka, (d, m.kv_lora_rank + m.qk_rope_head_dim), dtype),
+        "kv_norm": init_rmsnorm(m.kv_lora_rank, dtype),
+        "wkv_b": dense_init(kb, (m.kv_lora_rank, nh * (m.qk_nope_head_dim + m.v_head_dim)),
+                            dtype),
+        "wo": dense_init(ko, (nh * m.v_head_dim, d), dtype,
+                         scale=(nh * m.v_head_dim) ** -0.5),
+    }
+
+
+def mla_qkv(cfg, params, x, angles):
+    """Latent attention's per-head q, k (B, S, H, nope+rope) and v (B, S, H, v).
+
+    The compressed c_kv (kv_lora_rank wide) is RMS-normed and up-projected
+    to each head's k_nope and v; the 64 rope dims of k come from x directly
+    and are shared by every head. q's and k's rope dims are rotated
+    (rotate-halves, ``rotary.py``).
+    """
+    m = cfg.mla
+    B, S, _ = x.shape
+    nh, nope, rope = cfg.n_heads, m.qk_nope_head_dim, m.qk_rope_head_dim
+    q = (x @ params["wq"]).reshape(B, S, nh, nope + rope)
+    kv = x @ params["wkv_a"]
+    c_kv = rmsnorm(params["kv_norm"], kv[..., :m.kv_lora_rank], cfg.norm_eps)
+    k_pe = kv[..., m.kv_lora_rank:][:, :, None, :]                 # (B, S, 1, rope)
+    kvb = (c_kv @ params["wkv_b"]).reshape(B, S, nh, nope + m.v_head_dim)
+    k_nope, v = kvb[..., :nope], kvb[..., nope:]
+    q_pe, k_pe = apply_rotary(q[..., nope:], angles), apply_rotary(k_pe, angles)
+    q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe, (B, S, nh, rope))], axis=-1)
+    return q, k, v
 
 
 def _project_q(cfg, params, x):
@@ -108,7 +150,7 @@ def sdpa(cfg, q, k, v, mask, *, window: Optional[int] = None):
         logits = jnp.where(mask[None, None, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
-    return out.reshape(B, Sq, nh, hd)
+    return out.reshape(B, Sq, nh, v.shape[-1])
 
 
 def chunked_sdpa(cfg, q, k, v, *, chunk: int):
@@ -145,7 +187,7 @@ def chunked_sdpa(cfg, q, k, v, *, chunk: int):
         return None, jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
 
     _, outs = jax.lax.scan(f, None, (qs, jnp.arange(nc)))
-    out = jnp.moveaxis(outs, 0, 1).reshape(B, Sp, nh, hd)
+    out = jnp.moveaxis(outs, 0, 1).reshape(B, Sp, nh, v.shape[-1])
     return out[:, :S] if pad else out
 
 
@@ -165,13 +207,29 @@ def full_attention(cfg, params, x, angles, *, causal: bool = True,
 
     memory: (B, M, D) for cross-attention (no mask, keys from memory).
     Returns (out, (k, v)) when return_kv (pre-repeat KV for cache seeding).
+    Latent attention (``cfg.mla``) runs under the ``fednano.mla`` scope and
+    has no cache yet (ROADMAP R2).
     """
+    if cfg.mla is not None:
+        if return_kv or memory is not None:
+            raise NotImplementedError("latent attention has no KV cache or cross "
+                                      "attention yet (ROADMAP R2)")
+        with jax.named_scope("fednano.mla"):
+            return _attend(cfg, params, x, *mla_qkv(cfg, params, x, angles), causal)
     q = _project_q(cfg, params, x)
     kv_src = memory if memory is not None else x
     k, v = _project_kv(cfg, params, kv_src)
     if angles is not None and memory is None:
         q = apply_rotary(q, angles)
         k = apply_rotary(k, angles)
+    out = _attend(cfg, params, x, q, k, v, causal, memory)
+    if return_kv:
+        return out, (k, v)
+    return out
+
+
+def _attend(cfg, params, x, q, k, v, causal, memory=None):
+    """Attention of projected q, k, v, then the output projection."""
     # Context-parallel queries for head counts that don't divide the model
     # axis (qwen1.5: 20 heads vs 16): instead of replicating the whole
     # attention block (16x wasted FLOPs), shard the QUERY sequence over
@@ -210,12 +268,9 @@ def full_attention(cfg, params, x, angles, *, causal: bool = True,
     else:
         out = sdpa(cfg, q, k, v, mask)
     B, S = x.shape[:2]
-    out = out.reshape(B, S, cfg.n_heads * cfg.resolved_head_dim)
+    out = out.reshape(B, S, cfg.n_heads * v.shape[-1])
     out = out @ params["wo"]
-    out = constrain(out, residual_spec(cfg))
-    if return_kv:
-        return out, (k, v)
-    return out
+    return constrain(out, residual_spec(cfg))
 
 
 # ---------------------------------------------------------------------------

@@ -66,7 +66,9 @@ def init_norm(cfg, d: int, dtype=jnp.float32):
 
 
 def norm(cfg, params, x):
-    return layernorm(params, x) if cfg.norm == "layernorm" else rmsnorm(params, x)
+    if cfg.norm == "layernorm":
+        return layernorm(params, x)
+    return rmsnorm(params, x, cfg.norm_eps)
 
 
 # ---------------------------------------------------------------------------

@@ -62,9 +62,10 @@ def make_angles(cfg, positions):
     """Dispatch on cfg.pos_type.
 
     positions: (B, S) int32 for rope; (3, B, S) for mrope. Returns
-    (B, S, head_dim/2) angles, or None for learned/none position types.
+    (B, S, rotary_dim/2) angles, or None for learned/none position types.
+    The rotary dims are the head's, or latent attention's rope dims.
     """
-    hd = cfg.resolved_head_dim
+    hd = cfg.mla.qk_rope_head_dim if cfg.mla is not None else cfg.resolved_head_dim
     if cfg.pos_type == "rope":
         return rope_angles(positions, hd, cfg.rope_theta)
     if cfg.pos_type == "mrope":

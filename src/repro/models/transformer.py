@@ -6,7 +6,9 @@ size is O(1) in depth — 80-layer dry-runs stay tractable — with optional
 
 Layer bodies by family:
     dense/vlm : x += attn(norm(x));  x += mlp(norm(x))
-    moe       : x += attn(norm(x));  x += moe(norm(x))   (+ shared expert)
+    moe       : x += attn(norm(x));  x += moe(norm(x))   (+ shared expert),
+                after ``n_dense_layers`` leading dense layers (their own
+                stack, ``lead``, with MLP width ``dense_d_ff``)
     ssm       : x += mamba2(norm(x))
     hybrid    : 12 × (rec, rec, attn) triples + 2 trailing rec layers,
                 every sub-layer followed by its own MLP (Griffin residual
@@ -54,6 +56,11 @@ def init_dense_layer(key, cfg, dtype):
         "norm2": init_norm(cfg, cfg.d_model, dtype),
         "mlp": init_mlp(k2, cfg, dtype=dtype),
     }
+
+
+def _lead_cfg(cfg):
+    """The leading dense layers' view of an MoE config: their MLP width."""
+    return cfg.with_(d_ff=cfg.dense_d_ff or cfg.d_ff)
 
 
 def init_moe_layer(key, cfg, dtype):
@@ -124,10 +131,17 @@ def init_stack(key, cfg, dtype):
         "moe": init_moe_layer,
         "ssm": init_ssm_layer,
     }[cfg.family]
+    n_lead = cfg.n_dense_layers if cfg.family == "moe" else 0
+    if n_lead:
+        key, kl = jax.random.split(key)
     layers = jax.vmap(lambda k: init_one(k, cfg, dtype))(
-        jax.random.split(key, cfg.n_layers)
+        jax.random.split(key, cfg.n_layers - n_lead)
     )
-    return {"layers": layers}
+    if not n_lead:
+        return {"layers": layers}
+    lead = jax.vmap(lambda k: init_dense_layer(k, _lead_cfg(cfg), dtype))(
+        jax.random.split(kl, n_lead))
+    return {"lead": lead, "layers": layers}
 
 
 # ---------------------------------------------------------------------------
@@ -250,6 +264,10 @@ def forward_stack(cfg, stack, x, angles):
                                    stack["extras"], cfg.remat, cfg.scan_layers)
             aux = aux + aux2
         return x, aux
+    if "lead" in stack:
+        lead = functools.partial(dense_body, cfg)
+        x, _ = _scan_layers(lambda c, lp: lead(lp, c, angles), x, stack["lead"],
+                            cfg.remat, cfg.scan_layers)
     body = functools.partial(_BODY[cfg.family], cfg)
     return _scan_layers(lambda c, lp: body(lp, c, angles), x, stack["layers"],
                         cfg.remat, cfg.scan_layers)
@@ -315,7 +333,11 @@ def prefill_stack(cfg, stack, x, angles, capacity: int, length=None):
     updates so right-padding never leaks into the terminal decode state.
     Attention caches need no masking: pad KV is position-invalidated and
     overwritten before it becomes reachable (see serving.engine docstring).
+    Latent attention has no cache yet (ROADMAP R2).
     """
+    if "lead" in stack:
+        raise NotImplementedError("a stack with leading dense layers has no "
+                                  "decode state yet (ROADMAP R2)")
     if cfg.family == "hybrid":
         acfg = _attn_cfg(cfg)
         acap = attn_lib.cache_capacity(acfg, capacity)
@@ -355,24 +377,19 @@ def prefill_stack(cfg, stack, x, angles, capacity: int, length=None):
 # decode: one token
 # ---------------------------------------------------------------------------
 
-def _attn_step(cfg, lp, x, attend, cache, per_row: bool = False):
+def _attn_step(cfg, lp, x, attend, cache):
     """Attention then MLP (or MoE) residual block of one decode token.
 
     ``attend(cfg, attn_params, h, cache) -> (out, kv)`` is the attention
-    sub-layer and what it emits for the cache. ``per_row`` routes each
-    row's token through the experts on its own, as a batch of one: expert
-    capacity is shared by the tokens of a batch, so pages decoded together
-    must not compete for it.
+    sub-layer and what it emits for the cache. The expert layer is dropless,
+    so rows decoded together never compete for an expert.
     """
     out, kv = attend(cfg, lp["attn"], norm(cfg, lp["norm1"], x), cache)
     x = x + out
     h = norm(cfg, lp["norm2"], x)
     if "moe" not in lp:
         return x + mlp(cfg, lp["mlp"], h), kv
-    if per_row:
-        y = jax.vmap(lambda r: moe_apply(cfg, lp["moe"], r[None])[0][0])(h)
-    else:
-        y, _ = moe_apply(cfg, lp["moe"], h)
+    y, _ = moe_apply(cfg, lp["moe"], h)
     return x + y, kv
 
 
@@ -390,7 +407,7 @@ def _ssm_step(cfg, lp, x, state):
     return x + out, state
 
 
-def _decode_layers(cfg, stack, x, state, attend, per_row: bool = False):
+def _decode_layers(cfg, stack, x, state, attend):
     """Layer loop of one decode token over the stacked state.
 
     Each attention sub-layer's cache is replaced by what ``attend`` emits
@@ -425,7 +442,7 @@ def _decode_layers(cfg, stack, x, state, attend, per_row: bool = False):
 
     def f(c, inp):
         lp, st = inp
-        return _attn_step(cfg, lp, c, attend, st, per_row)
+        return _attn_step(cfg, lp, c, attend, st)
 
     x, caches = _scan_emit(f, x, (stack["layers"], state["layers"]), cfg.scan_layers)
     return x, {"layers": caches}
@@ -455,7 +472,7 @@ def decode_pages_stack(cfg, stack, x, angles, pool, pos):
     def attend(acfg, p, h, cache):
         return attn_lib.page_decode_attention(acfg, p, h, angles, cache, pos)
 
-    x, emitted = _decode_layers(cfg, stack, x, pool, attend, per_row=True)
+    x, emitted = _decode_layers(cfg, stack, x, pool, attend)
 
     def merge(old, new):
         if isinstance(old, attn_lib.KVCache):

@@ -93,6 +93,11 @@ class ServingEngine:
                  use_pallas_grouped: bool = False):
         from repro.models.vision_stub import num_patches
 
+        if cfg.mla is not None:
+            raise ValueError(
+                f"{cfg.name}: latent attention (MLA) cannot be served yet: the "
+                "page pool holds per-head K/V (L, P, C, n_kv, hd), and a "
+                "compressed-latent page pool is still missing (ROADMAP R2)")
         self.cfg = cfg
         self.backbone = backbone
         self.max_slots = max_slots

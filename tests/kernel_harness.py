@@ -6,7 +6,7 @@ One place defines, per kernel family:
     and block-boundary ±1 cases;
   * the kernel/ref pair to compare (kernels run ``interpret=True``);
   * gradient cases (``jax.grad`` of kernel vs ``jax.grad`` of ref) for the
-    families with custom VJPs (lora, flash_attention);
+    families with custom VJPs (lora, flash_attention, moe_gmm);
   * the tolerance policy — ALL tolerance literals live in ``TOLERANCES`` /
     ``TOLERANCE_OVERRIDES`` below, nothing is scattered through test files.
 
@@ -40,6 +40,7 @@ import numpy as np
 from repro.kernels.fisher_merge import ops as fm_ops, ref as fm_ref
 from repro.kernels.flash_attention import ops as fa_ops, ref as fa_ref
 from repro.kernels.lora import ops as lora_ops, ref as lora_ref
+from repro.kernels.moe_gmm import ops as gmm_ops, ref as gmm_ref
 from repro.kernels.ssd_scan import ops as ssd_ops, ref as ssd_ref
 
 # --------------------------------------------------------------------------
@@ -61,6 +62,8 @@ TOLERANCE_OVERRIDES: Dict[Tuple[str, str], Dict[str, float]] = {
     # gradient chains double the depth of the forward reduction
     ("flash_attention_grad", "float32"): {"rtol": 2e-6, "atol_scale": 2e-6},
     ("flash_attention_grad", "bfloat16"): {"rtol": 3e-2, "atol_scale": 3e-2},
+    ("flash_attention_vwidth_grad", "float32"): {"rtol": 2e-6, "atol_scale": 2e-6},
+    ("flash_attention_vwidth_grad", "bfloat16"): {"rtol": 3e-2, "atol_scale": 3e-2},
 }
 
 DTYPES = (jnp.float32, jnp.bfloat16)
@@ -350,6 +353,135 @@ def _flash_grad_cases() -> List[GradCase]:
             out.append(GradCase("flash_attention", shape[0], jnp.dtype(dtype).name,
                                 make(shape, dtype)))
     return out
+
+
+# --------------------------------------------------------------------------
+# flash attention with a v width of its own (latent attention: q/k 192,
+# v 128 at full size): block boundaries, GQA, a v wider than q/k
+# --------------------------------------------------------------------------
+
+FLASH_VWIDTH_SHAPES = [
+    # (label, b, sq, h, hkv, d, dv, bq, bk)
+    ("mla-exact", 1, 16, 2, 2, 48, 32, 16, 16),
+    ("mla-bound+1", 2, 17, 2, 2, 48, 32, 16, 16),
+    ("gqa-narrow-v", 1, 24, 4, 2, 64, 16, 16, 16),
+    ("wide-v", 1, 15, 2, 2, 16, 64, 16, 16),
+]
+
+
+def _flash_vwidth_args(rng, b, sq, h, hkv, d, dv, dtype):
+    q = jax.random.normal(rng, (b, sq, h, d), dtype)
+    k = jax.random.normal(jax.random.fold_in(rng, 1), (b, sq, hkv, d), dtype)
+    v = jax.random.normal(jax.random.fold_in(rng, 2), (b, sq, hkv, dv), dtype)
+    return q, k, v
+
+
+def _flash_vwidth_case(shape, dtype):
+    _, b, sq, h, hkv, d, dv, bq, bk = shape
+
+    def run(rng):
+        q, k, v = _flash_vwidth_args(rng, b, sq, h, hkv, d, dv, dtype)
+        got = fa_ops.flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk,
+                                     interpret=True)
+        assert got.shape == (b, sq, h, dv)
+        return got, fa_ref.attention(q, k, v, causal=True)
+
+    return run
+
+
+@register_kernel("flash_attention_vwidth")
+def _flash_vwidth_cases() -> List[Case]:
+    return [Case("flash_attention_vwidth", shape[0], jnp.dtype(dtype).name,
+                 _flash_vwidth_case(shape, dtype))
+            for dtype in DTYPES for shape in FLASH_VWIDTH_SHAPES]
+
+
+@register_grads("flash_attention_vwidth")
+def _flash_vwidth_grad_cases() -> List[GradCase]:
+    def make(shape, dtype):
+        _, b, sq, h, hkv, d, dv, bq, bk = shape
+
+        def run(rng):
+            q, k, v = _flash_vwidth_args(rng, b, sq, h, hkv, d, dv, dtype)
+
+            def lk(q, k, v):
+                y = fa_ops.flash_attention(q, k, v, causal=True, block_q=bq,
+                                           block_k=bk, interpret=True)
+                return jnp.sum(y.astype(jnp.float32) ** 2)
+
+            def lr(q, k, v):
+                return jnp.sum(fa_ref.attention(q, k, v, causal=True)
+                               .astype(jnp.float32) ** 2)
+
+            return (jax.grad(lk, argnums=(0, 1, 2))(q, k, v),
+                    jax.grad(lr, argnums=(0, 1, 2))(q, k, v))
+
+        return run
+
+    return [GradCase("flash_attention_vwidth", shape[0], jnp.dtype(dtype).name,
+                     make(shape, dtype))
+            for dtype in DTYPES for shape in FLASH_VWIDTH_SHAPES[1:3]]
+
+
+# --------------------------------------------------------------------------
+# grouped expert matmul — rows sorted by held expert, the last group the
+# rows of no held expert; an empty group, one group holding every row,
+# groups across row-tile boundaries, ragged k and n
+# --------------------------------------------------------------------------
+
+GMM_SHAPES = [
+    # (label, k, n, group sizes: held groups then the rows of none)
+    ("mixed", 32, 48, (5, 0, 20, 7)),
+    ("one-takes-all", 24, 16, (0, 40, 0, 0)),
+    ("none-held", 16, 24, (0, 0, 9)),
+    ("ragged", 40, 136, (3, 17, 1, 12, 4)),
+]
+
+
+def _gmm_args(rng, k, n, sizes, dtype):
+    m = sum(sizes)
+    lhs = jax.random.normal(rng, (m, k), dtype)
+    rhs = (jax.random.normal(jax.random.fold_in(rng, 1), (len(sizes) - 1, k, n))
+           * k ** -0.5).astype(dtype)
+    return lhs, rhs, jnp.asarray(sizes, jnp.int32)
+
+
+def _gmm_case(shape, dtype):
+    _, k, n, sizes = shape
+
+    def run(rng):
+        lhs, rhs, gs = _gmm_args(rng, k, n, sizes, dtype)
+        got = gmm_ops.gmm(lhs, rhs, gs, interpret=True)
+        return got, gmm_ref.grouped_matmul(lhs, rhs, gs)
+
+    return run
+
+
+@register_kernel("moe_gmm")
+def _gmm_cases() -> List[Case]:
+    return [Case("moe_gmm", shape[0], jnp.dtype(dtype).name, _gmm_case(shape, dtype))
+            for dtype in DTYPES for shape in GMM_SHAPES]
+
+
+@register_grads("moe_gmm")
+def _gmm_grad_cases() -> List[GradCase]:
+    """The input gradient only: the weights are frozen, and the kernel's VJP
+    gives them no cotangent."""
+    def make(shape, dtype):
+        _, k, n, sizes = shape
+
+        def run(rng):
+            lhs, rhs, gs = _gmm_args(rng, k, n, sizes, dtype)
+            lk = lambda x: jnp.sum(gmm_ops.gmm(x, rhs, gs, interpret=True)
+                                   .astype(jnp.float32) ** 2)
+            lr = lambda x: jnp.sum(gmm_ref.grouped_matmul(x, rhs, gs)
+                                   .astype(jnp.float32) ** 2)
+            return (jax.grad(lk)(lhs),), (jax.grad(lr)(lhs),)
+
+        return run
+
+    return [GradCase("moe_gmm", shape[0], jnp.dtype(dtype).name, make(shape, dtype))
+            for dtype in DTYPES for shape in GMM_SHAPES[::3]]
 
 
 # --------------------------------------------------------------------------

@@ -3,7 +3,7 @@
 Interpret mode (every other kernel test) checks numerics but not what the
 chip's compiler accepts: block tiling, fast-memory limits, Mosaic lowering.
 These tests lower each kernel at real widths (llava-1.5-7b; SSD at
-mamba2-130m) against a described ``v5e:2x2`` topology — no chip needed —
+mamba2-130m; latent attention and the grouped expert matmul at kimi-vl-a3b) against a described ``v5e:2x2`` topology — no chip needed —
 and assert the compiled program holds the kernel (``tpu_custom_call``), so
 a refusal shows up here instead of on the chip.
 
@@ -20,6 +20,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.fisher_merge import ops as fm_ops
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.lora import ops as lora_ops
+from repro.kernels.moe_gmm import ops as gmm_ops
 from repro.kernels.ssd_scan import ops as ssd_ops
 
 # llava-1.5-7b: d_model 4096, 32 heads of 128, rank-64 NanoAdapters; a train
@@ -131,3 +132,32 @@ def test_ssd_scan_compiles(one_chip):
         _spec(one_chip, (h,), jnp.float32),
         _spec(one_chip, (b, s, n)),
         _spec(one_chip, (b, s, n)))
+
+
+# kimi-vl-a3b: 16 latent-attention heads, q/k 192 wide, v 128; 16 held
+# experts of 1408 over d_model 2048, one client's 2 x 1280 positions x top-6
+MLA_HEADS, QK_DIM, V_DIM, MLA_SEQ = 16, 192, 128, 1280
+EXPERTS, D_EXPERT, D_KIMI, COPIES = 16, 1408, 2048, 2 * 1280 * 6
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_flash_attention_with_its_own_v_width_compiles(one_chip, grad):
+    qk = _spec(one_chip, (2, MLA_SEQ, MLA_HEADS, QK_DIM))
+    v = _spec(one_chip, (2, MLA_SEQ, MLA_HEADS, V_DIM))
+    fn = (jax.grad(_flash_loss, argnums=(0, 1, 2)) if grad
+          else lambda q, k, v: fa_ops.flash_attention(q, k, v, causal=True,
+                                                      interpret=False))
+    _compile_has_kernel(fn, qk, qk, v)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_grouped_expert_matmul_compiles(one_chip, grad):
+    """Forward, and the input-gradient VJP alone: no weight-gradient kernel."""
+    rows = _spec(one_chip, (COPIES, D_KIMI))
+    w = _spec(one_chip, (EXPERTS, D_KIMI, D_EXPERT))
+    sizes = _spec(one_chip, (EXPERTS + 1,), jnp.int32)
+    fwd = lambda x, w, g: gmm_ops.gmm(x, w, g, interpret=False)  # noqa: E731
+    fn = (jax.grad(lambda x, w, g: jnp.sum(fwd(x, w, g).astype(jnp.float32) ** 2))
+          if grad else fwd)
+    compiled = _compile_has_kernel(fn, rows, w, sizes)
+    assert compiled.as_text().count("tpu_custom_call") == (2 if grad else 1)

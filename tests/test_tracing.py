@@ -7,10 +7,12 @@ cache hits) equal what the shapes and the cache's own counters say.
 """
 import glob
 import os
+import re
 from dataclasses import dataclass
 from typing import Dict, List
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -216,3 +218,37 @@ def test_serving_spans_nest_and_carry_the_cache_counters(tmp_path):
         inner = [s.name for s in spans if s is not d and s.inside(d)]
         assert inner == ["fednano.serve.decode.dispatch", "fednano.serve.decode.wait",
                          "fednano.serve.decode.bookkeep"]
+
+
+def test_expert_and_latent_attention_scopes_are_in_the_round_program():
+    """The device scopes of latent attention and of the expert layer survive
+    vmap over clients, remat and the VJP into the lowered round program's
+    op metadata (where a device trace that keeps it can read them)."""
+    from repro.core.client import make_many_update
+    from repro.optim import adamw_init
+    from repro.strategies import get_strategy
+
+    cfg = get_smoke_config("kimi-vl-a3b").with_(use_pallas=True, remat=True)
+    backbone = jax.eval_shape(lambda k: model_lib._init_backbone(k, cfg),
+                              jax.random.PRNGKey(0))
+    adp = jax.eval_shape(lambda k: nano.init_nanoedge(k, cfg), jax.random.PRNGKey(0))
+    k, t, b, s, m = 3, 2, 2, 8, 4
+    stack = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct((k,) + a.shape, a.dtype), tree)
+    opt = stack(jax.eval_shape(adamw_init, adp))
+    sd = jax.ShapeDtypeStruct
+    rows = lambda n: nano.Batch(  # noqa: E731
+        sd((k, n, b, s), jnp.int32), sd((k, n, b, s), jnp.int32),
+        sd((k, n, b, s), jnp.float32), sd((k, n, b, m, cfg.frontend_dim), jnp.float32))
+    fn = make_many_update(cfg, get_strategy("fednano"), HyperParams(local_steps=t,
+                          fisher_batches=1), downloads=True, warmup=False,
+                          has_local=False, train_t=t, warm_t=0, fish_t=1,
+                          shared_batches=False)
+    text = fn.lower(backbone, adp, None, opt, None, None, rows(t), None,
+                    rows(1)).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    for scope in ("fednano.mla", "fednano.moe.route", "fednano.moe.dispatch",
+                  "fednano.moe.experts", "fednano.moe.shared"):
+        # in the forward, and in the backward of the rematerialised layer
+        assert any(scope in n and "transpose(" not in n for n in names), scope
+        assert any(scope in n and "transpose(" in n for n in names), scope
