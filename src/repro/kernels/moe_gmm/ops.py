@@ -7,8 +7,9 @@ gradient (megablox's ``tgmm``) is never traced, compiled or run; the
 cotangent of ``rhs`` is a symbolic zero.
 
 Rows are padded to a whole row tile inside the launcher and cut after, so
-any row count works. ``interpret=None`` follows the platform
-(``repro.kernels.platform``); the tiling comes from ``repro.kernels.tuning``.
+any row count works; the pad rows belong to no group and are not visited.
+``interpret=None`` follows the platform (``repro.kernels.platform``); the
+tiling comes from ``repro.kernels.tuning``.
 """
 from __future__ import annotations
 
@@ -30,8 +31,7 @@ def _gmm_jit(lhs, rhs, group_sizes, *, transpose_rhs, interpret):
     tiling = tuning.gmm_tiling(m, k, n)
     pad = (-m) % tiling[0]
     x = jnp.pad(lhs, ((0, pad), (0, 0))) if pad else lhs
-    sizes = group_sizes.at[-1].add(pad) if pad else group_sizes
-    out = grouped_matmul(x, rhs, sizes, tiling=tiling, transpose_rhs=transpose_rhs,
+    out = grouped_matmul(x, rhs, group_sizes, tiling=tiling, transpose_rhs=transpose_rhs,
                          interpret=interpret)
     return out[:m] if pad else out
 
@@ -59,8 +59,8 @@ _gmm.defvjp(_gmm_fwd, _gmm_bwd)
 def gmm(lhs, rhs, group_sizes, *, transpose_rhs: bool = False,
         interpret: Optional[bool] = None):
     """lhs (m, k) rows sorted by group; rhs (G, k, n) (``transpose_rhs``:
-    (G, n, k)); group_sizes (G + 1,) int32, the last entry the rows of no
-    held group, which come out as zeros. Returns (m, n) in lhs's type.
-    Differentiable in ``lhs`` only."""
+    (G, n, k)); group_sizes (G,) int32, summing to at most m. Returns (m, n)
+    in lhs's type; rows past the groups' are unspecified, and their
+    cotangent reaches no other row. Differentiable in ``lhs`` only."""
     return _gmm(lhs, rhs, group_sizes.astype(jnp.int32), transpose_rhs,
                 interpret_mode(interpret))

@@ -6,9 +6,10 @@ import jax.numpy as jnp
 
 def grouped_matmul(lhs, rhs, group_sizes, *, transpose_rhs: bool = False):
     """lhs (m, k) rows sorted by group; rhs (G, k, n) (``transpose_rhs``:
-    (G, n, k)); group_sizes (G + 1,), the last entry the rows of no held
-    group. Row r of group g gets ``lhs[r] @ rhs[g]``; rows past the held
-    groups get zeros. Float32 accumulation, output in lhs's type."""
+    (G, n, k)); group_sizes (G,), summing to at most m. Row r of group g
+    gets ``lhs[r] @ rhs[g]``; rows past the groups get zeros here (the
+    kernel leaves them unspecified). Float32 accumulation, output in lhs's
+    type."""
     ends = jnp.cumsum(group_sizes)
     starts = ends - group_sizes
     row = jnp.arange(lhs.shape[0])

@@ -13,9 +13,13 @@ grouped matmuls over the sorted rows (``kernels/moe_gmm`` with
 ``use_pallas``, else ``jax.lax.ragged_dot``): each expert takes exactly
 the rows routed to it, with no capacity and no dropped token, so one
 token's output never depends on which other tokens share its batch. The
-rows are unsorted, weighed and summed per token. What the absent experts
-would add is left out; no code stands in for the other chips. The shared
-experts (one MLP of width ``shared_d_ff``) are added for every token.
+rows are unsorted, weighed and summed per token. The held copies sort
+first, so they are a prefix of the sorted rows: the grouped matmuls visit
+that prefix only, and the combine selects each token's held copies (XLA
+gathers, weighed and summed in float32, with no (T, K, D) array). What
+the absent experts would add is left out; no code stands in for the other
+chips. The shared experts (one MLP of width ``shared_d_ff``) are added
+for every token.
 
 The routed part is token-wise, so under ``jax.vmap`` (the round engine's
 vmap over clients) its batching rule folds the mapped axis into the
@@ -95,14 +99,42 @@ def route(mcfg, params, x):
 # ---------------------------------------------------------------------------
 
 def _grouped(use_pallas, lhs, rhs, sizes, transpose_rhs=False):
-    """Rows sorted by held expert -> each row times its expert's matrix;
-    rows past the held experts' (the last entry of ``sizes``) give zeros."""
+    """Rows sorted by held expert -> each row times its expert's matrix, for
+    the rows of the held experts (``sizes``); rows past them are
+    unspecified (zeros on the ``ragged_dot`` path)."""
     if use_pallas:
         from repro.kernels.moe_gmm import ops as gmm_ops
 
         return gmm_ops.gmm(lhs, rhs, sizes, transpose_rhs=transpose_rhs)
     w = jnp.swapaxes(rhs, 1, 2) if transpose_rhs else rhs
-    return jax.lax.ragged_dot(lhs, w, sizes[:-1]).astype(lhs.dtype)
+    return jax.lax.ragged_dot(lhs, w, sizes).astype(lhs.dtype)
+
+
+def _combine(rows, pos, held, w=None, *, out_dtype):
+    """Token t's held copies' rows, ``rows[pos[t, k]]`` where ``held[t, k]``,
+    times ``w[t, k]`` (1 where None), summed in float32 in the order of k and
+    cast once. Rows of copies not held are selected away, never multiplied:
+    what the rows past the held prefix hold reaches no output."""
+    acc = jnp.zeros((pos.shape[0], rows.shape[1]), jnp.float32)
+    for k in range(pos.shape[1]):
+        v = rows[pos[:, k]].astype(jnp.float32)
+        if w is not None:
+            v = v * w[:, k, None]
+        acc = jnp.where(held[:, k, None], acc + v, acc)
+    return acc.astype(out_dtype)
+
+
+def _dispatch_back(dy, order, pos, held, w, out):
+    """The backward pass's dispatch of dy (T, D): the experts' cotangent,
+    each sorted row's token's dy times that copy's weight, in float32 and
+    cast back (T*K, D); and the weights' gradient (T, K), the float32 dot
+    of a held copy's dy with its expert output ``out`` (T*K, D), 0 where
+    not held. Rows of ``out`` past the held prefix are selected away."""
+    g = dy[order // pos.shape[1]].astype(jnp.float32)
+    dout = (g * w.reshape(-1)[order][:, None]).astype(dy.dtype)
+    dots = jnp.einsum("md,md->m", g, out.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+    return dout, jnp.where(held, dots[pos], 0.0)
 
 
 def _act(act, gate, up):
@@ -115,7 +147,18 @@ def _act(act, gate, up):
 
 
 def _routed_fwd(mcfg, act, use_pallas, params, x):
-    """Routed experts' output of x (T, D), and what the backward pass reads."""
+    """Routed experts' output of x (T, D) and the copies each held expert
+    took, then what the backward pass reads: ``(y, sizes), (idx, held,
+    order, pos, sizes, gate, up, yk)``.
+
+    Sorted by key, the held experts' copies come first: they are the prefix
+    of ``order`` below n_held = sum(sizes), and a copy is held exactly when
+    its sorted position ``pos`` (T, K) lies below n_held. The grouped
+    matmuls visit the prefix only, so the experts' rows past it are
+    unspecified and are read under a select alone. ``yk`` (T, K, D) is each
+    copy's expert output in token order, zeros where not held: the backward
+    pass does not read it, so a jitted caller never builds it; it is there
+    for code that edits the output copy by copy."""
     T, D = x.shape
     K, E_h = mcfg.top_k, mcfg.held
     with jax.named_scope("fednano.moe.route"):
@@ -125,34 +168,38 @@ def _routed_fwd(mcfg, act, use_pallas, params, x):
         held = (local >= 0) & (local < E_h)
         key = jnp.where(held, local, E_h).reshape(-1)             # (T*K,)
         order = jnp.argsort(key, stable=True)
-        inv = jnp.argsort(order)
-        sizes = jnp.bincount(key, length=E_h + 1).astype(jnp.int32)
+        pos = jnp.argsort(order).astype(jnp.int32).reshape(T, K)
+        sizes = jnp.bincount(key, length=E_h + 1)[:E_h].astype(jnp.int32)
         rows = x[order // K]                                        # (T*K, D)
     with jax.named_scope("fednano.moe.experts"):
         up = _grouped(use_pallas, rows, params["w_up"], sizes)
         gate = up if act == "gelu" else _grouped(use_pallas, rows, params["w_gate"], sizes)
-        out = _grouped(use_pallas, _act(act, gate, up), params["w_down"], sizes)
+        out = _down(use_pallas, act, params, gate, up, sizes)
     with jax.named_scope("fednano.moe.dispatch"):
-        yk = out[inv].reshape(T, K, D)
-        wk = jnp.where(held, w, 0.0)
-        y = jnp.einsum("tk,tkd->td", wk, yk.astype(jnp.float32)).astype(x.dtype)
-        counts = sizes[:E_h]
-    return (y, counts), (idx, held, order, inv, sizes, gate, up, yk)
+        y = _combine(out, pos, held, w, out_dtype=x.dtype)
+        yk = jnp.where(held[..., None], out[pos], 0).astype(out.dtype)
+    return (y, sizes), (idx, held, order, pos, sizes, gate, up, yk)
+
+
+def _down(use_pallas, act, params, gate, up, sizes):
+    """The held experts' down projection of their activations."""
+    return _grouped(use_pallas, _act(act, gate, up), params["w_down"], sizes)
 
 
 def _routed_bwd(mcfg, act, use_pallas, params, x, res, dy):
     """Input gradient of the routed part: through the held experts and
-    through the routing weights of their picks."""
-    idx, held, order, inv, sizes, gate, up, yk = res
-    T, D = x.shape
-    K = mcfg.top_k
-    dy32 = dy.astype(jnp.float32)
+    through the routing weights of their picks. The experts' outputs, which
+    the routing weights' gradient reads, are projected down again here:
+    under rematerialisation the forward's projection is recomputed anyway,
+    and this one takes its place."""
+    idx, held, order, pos, sizes, gate, up = res
     with jax.named_scope("fednano.moe.route"):
         scores = route_scores(mcfg, params["router"], x)
-        wk = jnp.where(held, pick_weights(mcfg, scores, idx), 0.0)
-        dw = jnp.where(held, jnp.einsum("td,tkd->tk", dy32, yk.astype(jnp.float32)), 0.0)
+        w = pick_weights(mcfg, scores, idx)
+    with jax.named_scope("fednano.moe.experts"):
+        out = _down(use_pallas, act, params, gate, up, sizes)
     with jax.named_scope("fednano.moe.dispatch"):
-        dout = (dy32[:, None, :] * wk[..., None]).astype(x.dtype).reshape(T * K, D)[order]
+        dout, dw = _dispatch_back(dy, order, pos, held, w, out)
     with jax.named_scope("fednano.moe.experts"):
         dh = _grouped(use_pallas, dout, params["w_down"], sizes, transpose_rhs=True)
         dgate, dup = jax.vjp(functools.partial(_act, act), gate, up)[1](dh)
@@ -161,7 +208,7 @@ def _routed_bwd(mcfg, act, use_pallas, params, x, res, dy):
             drows = drows + _grouped(use_pallas, dgate, params["w_gate"], sizes,
                                      transpose_rhs=True)
     with jax.named_scope("fednano.moe.dispatch"):
-        dx = jnp.sum(drows[inv].reshape(T, K, D).astype(jnp.float32), axis=1)
+        dx = _combine(drows, pos, held, out_dtype=jnp.float32)
     with jax.named_scope("fednano.moe.route"):
         _, vjp = jax.vjp(lambda xx: pick_weights(
             mcfg, route_scores(mcfg, params["router"], xx), idx), x)
@@ -196,29 +243,30 @@ def _folded(fn):
 @functools.lru_cache(maxsize=None)
 def _routed_call(mcfg, act: str, use_pallas: bool):
     """The routed part of one MoE config as a function of (params, x (T, D))
-    returning (y (T, D), tokens per held expert (E_held,)), differentiable
-    in x."""
+    returning (y (T, D), tokens per held expert (E_held,), the held share of
+    the T x K copies), differentiable in x."""
 
     def fwd_fn(params, glob, x):
-        (y, counts), (idx, held, order, inv, sizes, gate, up, yk) = _routed_fwd(
+        (y, sizes), (idx, held, order, pos, _, gate, up, _) = _routed_fwd(
             mcfg, act, use_pallas, params, x)
-        return (y, idx, held, order, inv, gate, up, yk), (counts, sizes)
+        share = jnp.sum(sizes) / (x.shape[0] * mcfg.top_k)
+        return (y, idx, held, order, pos, gate, up), (sizes, share)
 
-    def bwd_fn(params, glob, x, dy, idx, held, order, inv, gate, up, yk):
+    def bwd_fn(params, glob, x, dy, idx, held, order, pos, gate, up):
         (sizes,) = glob
-        res = (idx, held, order, inv, sizes, gate, up, yk)
+        res = (idx, held, order, pos, sizes, gate, up)
         return (_routed_bwd(mcfg, act, use_pallas, params, x, res, dy),), ()
 
     fwd, bwd = _folded(fwd_fn), _folded(bwd_fn)
 
     @jax.custom_vjp
     def routed(params, x):
-        (y, *_), (counts, _) = fwd(params, (), x)
-        return y, counts
+        (y, *_), (sizes, share) = fwd(params, (), x)
+        return y, sizes, share
 
     def routed_f(params, x):
-        (y, *res), (counts, sizes) = fwd(params, (), x)
-        return (y, counts), (params, x, sizes, res)
+        (y, *res), (sizes, share) = fwd(params, (), x)
+        return (y, sizes, share), (params, x, sizes, res)
 
     def routed_b(saved, g):
         params, x, sizes, res = saved
@@ -233,15 +281,16 @@ def moe_apply(cfg, params, x):
     """x (B, S, D) -> (y (B, S, D), aux).
 
     aux: ``lb_loss`` (GShard load-balance term over every expert's top-1
-    share, reported only: the backbone is frozen) and ``tokens`` (E_held,),
-    the token copies each held expert computed (the whole batch's under
-    vmap).
+    share, reported only: the backbone is frozen), ``tokens`` (E_held,),
+    the token copies each held expert computed, and ``held_share``, those
+    copies over all T x top-k, the share of rows the dispatch moves (both
+    the whole batch's under vmap).
     """
     mcfg = cfg.moe
     B, S, D = x.shape
     routed = _routed_call(mcfg, cfg.act, bool(cfg.use_pallas))
-    y, counts = routed({k: v for k, v in params.items() if k != "shared"},
-                       x.reshape(B * S, D))
+    y, counts, share = routed({k: v for k, v in params.items() if k != "shared"},
+                              x.reshape(B * S, D))
     y = y.reshape(B, S, D)
     if "shared" in params:
         with jax.named_scope("fednano.moe.shared"):
@@ -251,4 +300,4 @@ def moe_apply(cfg, params, x):
             route_scores(mcfg, params["router"], x.reshape(B * S, D)))
         top1 = jax.nn.one_hot(jnp.argmax(scores, axis=-1), mcfg.n_experts)
         lb = mcfg.n_experts * jnp.sum(jnp.mean(top1, 0) * jnp.mean(scores, 0))
-    return y, {"lb_loss": lb, "tokens": counts}
+    return y, {"lb_loss": lb, "tokens": counts, "held_share": share}

@@ -4,9 +4,12 @@ One place defines, per kernel family:
 
   * the dtype × shape grid — exact block multiples, ragged tails, odd sizes,
     and block-boundary ±1 cases;
-  * the kernel/ref pair to compare (kernels run ``interpret=True``);
+  * the kernel/ref pair to compare (kernels run ``interpret=True``); the
+    expert layer's dispatch, plain XLA with no kernel of its own, registers
+    its contract against a written-out loop the same way;
   * gradient cases (``jax.grad`` of kernel vs ``jax.grad`` of ref) for the
-    families with custom VJPs (lora, flash_attention, moe_gmm);
+    families with custom VJPs (lora, flash_attention, moe_gmm, and
+    moe_dispatch through the expert layer's VJP);
   * the tolerance policy — ALL tolerance literals live in ``TOLERANCES`` /
     ``TOLERANCE_OVERRIDES`` below, nothing is scattered through test files.
 
@@ -424,9 +427,11 @@ def _flash_vwidth_grad_cases() -> List[GradCase]:
 
 
 # --------------------------------------------------------------------------
-# grouped expert matmul — rows sorted by held expert, the last group the
-# rows of no held expert; an empty group, one group holding every row,
-# groups across row-tile boundaries, ragged k and n
+# grouped expert matmul — rows sorted by held expert; the kernel gets the held
+# groups' sizes, and the rows past them (the table's last entry: the rows of
+# no held expert) are unspecified, so only the held prefix is compared; an
+# empty group, one group holding every row, groups across row-tile
+# boundaries, ragged k and n
 # --------------------------------------------------------------------------
 
 GMM_SHAPES = [
@@ -443,16 +448,16 @@ def _gmm_args(rng, k, n, sizes, dtype):
     lhs = jax.random.normal(rng, (m, k), dtype)
     rhs = (jax.random.normal(jax.random.fold_in(rng, 1), (len(sizes) - 1, k, n))
            * k ** -0.5).astype(dtype)
-    return lhs, rhs, jnp.asarray(sizes, jnp.int32)
+    return lhs, rhs, jnp.asarray(sizes[:-1], jnp.int32), sum(sizes[:-1])
 
 
 def _gmm_case(shape, dtype):
     _, k, n, sizes = shape
 
     def run(rng):
-        lhs, rhs, gs = _gmm_args(rng, k, n, sizes, dtype)
+        lhs, rhs, gs, held = _gmm_args(rng, k, n, sizes, dtype)
         got = gmm_ops.gmm(lhs, rhs, gs, interpret=True)
-        return got, gmm_ref.grouped_matmul(lhs, rhs, gs)
+        return got[:held], gmm_ref.grouped_matmul(lhs, rhs, gs)[:held]
 
     return run
 
@@ -466,22 +471,169 @@ def _gmm_cases() -> List[Case]:
 @register_grads("moe_gmm")
 def _gmm_grad_cases() -> List[GradCase]:
     """The input gradient only: the weights are frozen, and the kernel's VJP
-    gives them no cotangent."""
+    gives them no cotangent. The loss reads the held rows alone."""
     def make(shape, dtype):
         _, k, n, sizes = shape
 
         def run(rng):
-            lhs, rhs, gs = _gmm_args(rng, k, n, sizes, dtype)
-            lk = lambda x: jnp.sum(gmm_ops.gmm(x, rhs, gs, interpret=True)
-                                   .astype(jnp.float32) ** 2)
-            lr = lambda x: jnp.sum(gmm_ref.grouped_matmul(x, rhs, gs)
-                                   .astype(jnp.float32) ** 2)
-            return (jax.grad(lk)(lhs),), (jax.grad(lr)(lhs),)
+            lhs, rhs, gs, held = _gmm_args(rng, k, n, sizes, dtype)
+            live = (jnp.arange(lhs.shape[0]) < held)[:, None]
+            loss = lambda f: (lambda x: jnp.sum(
+                jnp.where(live, f(x).astype(jnp.float32), 0.0) ** 2))
+            gk = jax.grad(loss(lambda x: gmm_ops.gmm(x, rhs, gs, interpret=True)))(lhs)
+            gr = jax.grad(loss(lambda x: gmm_ref.grouped_matmul(x, rhs, gs)))(lhs)
+            return (gk[:held],), (gr[:held],)
 
         return run
 
     return [GradCase("moe_gmm", shape[0], jnp.dtype(dtype).name, make(shape, dtype))
             for dtype in DTYPES for shape in GMM_SHAPES[::3]]
+
+
+# --------------------------------------------------------------------------
+# expert dispatch — plain XLA in the expert layer (``models/moe.py``), no
+# kernel of its own; its contract is checked here against a per-copy loop:
+# the per-token combine over the held prefix of the sorted copies (weighed
+# in the rows' type, and plain float32) and the backward's weighed rows of
+# dy with the weights' gradient. n_held 0, 47-49 and T·K; T·K = 111 and
+# T = 37 are odd; "poison" fills every row past the prefix with NaN, which
+# must reach no output; "odd-width" has D = 48.
+# --------------------------------------------------------------------------
+
+DISPATCH_SHAPES = [
+    # (label, T, K, D, n_held)
+    ("mid", 37, 3, 256, 50),
+    ("none-held", 37, 3, 256, 0),
+    ("all-held", 37, 3, 256, 111),
+    ("block-1", 37, 3, 256, 47),
+    ("block", 37, 3, 256, 48),
+    ("block+1", 37, 3, 256, 49),
+    ("poison", 37, 3, 256, 50),
+    ("odd-width", 21, 4, 48, 30),
+]
+
+
+def _sorted_copies(rng, T, K, n, groups=3):
+    """A routing of T x K copies with exactly n held ones, in ``groups`` held
+    groups, sorted as the expert layer sorts them: (pos, order, sizes)."""
+    M = T * K
+    held = jnp.zeros((M,), bool).at[jax.random.permutation(rng, M)[:n]].set(True)
+    key = jnp.where(held, jax.random.randint(jax.random.fold_in(rng, 1), (M,), 0, groups),
+                    groups)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    pos = jnp.argsort(order).astype(jnp.int32).reshape(T, K)
+    return pos, order, jnp.bincount(key, length=groups + 1)[:groups].astype(jnp.int32)
+
+
+def _dispatch_loop(dy, pos, order, n, w, rows):
+    """The dispatch written out copy by copy in float64: the weighed and the
+    plain combine (T, D), the weighed rows of dy (n, D) and the weights'
+    gradient (T, K)."""
+    dy, w, rows = (np.asarray(a, np.float64) for a in (dy, w, rows))
+    pos, order = np.asarray(pos), np.asarray(order)
+    T, K = pos.shape
+    y, dx = np.zeros_like(dy), np.zeros_like(dy)
+    dw = np.zeros((T, K))
+    for t in range(T):
+        for k in range(K):
+            if pos[t, k] < n:
+                y[t] += w[t, k] * rows[pos[t, k]]
+                dx[t] += rows[pos[t, k]]
+                dw[t, k] = dy[t] @ rows[pos[t, k]]
+    dout = np.array([dy[j // K] * w.reshape(-1)[j] for j in order[:n]]).reshape(n, dy.shape[1])
+    return y, dx, dout, dw
+
+
+def _dispatch_case(shape, dtype):
+    label, T, K, D, n = shape
+
+    def run(rng):
+        from repro.models import moe
+
+        k = jax.random.split(rng, 4)
+        M = T * K
+        dy = jax.random.normal(k[0], (T, D), dtype)
+        pos, order, _ = _sorted_copies(k[1], T, K, n)
+        w = jax.random.uniform(k[2], (T, K), jnp.float32)
+        rows = jax.random.normal(k[3], (M, D), dtype)
+        want = _dispatch_loop(dy, pos, order, n, w, rows)
+        if label == "poison":
+            rows = jnp.where((jnp.arange(M) < n)[:, None], rows, jnp.nan)
+        held = pos < n
+        dout, dw = moe._dispatch_back(dy, order, pos, held, w, rows)
+        got = (moe._combine(rows, pos, held, w, out_dtype=dtype),
+               moe._combine(rows, pos, held, out_dtype=jnp.float32), dout[:n], dw)
+        flat = lambda out: jnp.concatenate([jnp.ravel(jnp.asarray(a, jnp.float32))
+                                            for a in out])
+        return flat(got), flat(want)
+
+    return run
+
+
+@register_kernel("moe_dispatch")
+def _dispatch_cases() -> List[Case]:
+    return [Case("moe_dispatch", shape[0], jnp.dtype(dtype).name,
+                 _dispatch_case(shape, dtype))
+            for dtype in DTYPES for shape in DISPATCH_SHAPES]
+
+
+def _moe_layer_cfg(share: bool):
+    """A small sigmoid + bias routed layer: 8 experts, top-3, this chip holding
+    experts 2-4 (``share``) or all of them."""
+    from repro.configs import get_smoke_config
+
+    base = get_smoke_config("kimi-vl-a3b")
+    moe = {"n_experts": 8, "top_k": 3}
+    if share:
+        moe.update(expert_first=2, experts_held=3)
+    return base.with_(moe=moe, use_pallas=True)
+
+
+def _plain_routed(cfg, p, x):
+    """The routed part written out: every held expert on every token, masked
+    by the picks (chosen on the stopped input), weighed by differentiable
+    routing weights."""
+    from repro.models import moe
+
+    mcfg = cfg.moe
+    idx = moe.choose(mcfg, moe.route_scores(mcfg, p["router"], jax.lax.stop_gradient(x)),
+                     p.get("bias"))
+    w = moe.pick_weights(mcfg, moe.route_scores(mcfg, p["router"], x), idx)
+    x32 = x.astype(jnp.float32)
+    y = jnp.zeros_like(x32)
+    for j in range(mcfg.held):
+        sel = jnp.sum(jnp.where(idx == mcfg.expert_first + j, w, 0.0), -1)
+        h = (jax.nn.silu(x32 @ p["w_gate"][j].astype(jnp.float32))
+             * (x32 @ p["w_up"][j].astype(jnp.float32)))
+        y = y + sel[:, None] * (h @ p["w_down"][j].astype(jnp.float32))
+    return y
+
+
+@register_grads("moe_dispatch")
+def _dispatch_grad_cases() -> List[GradCase]:
+    """The input gradient through the expert layer's custom VJP (gather,
+    grouped matmuls and combine on the held prefix, the routing weights'
+    VJP) against the written-out layer's autodiff."""
+    from repro.models import moe
+
+    def make(share, dtype):
+        def run(rng):
+            cfg = _moe_layer_cfg(share)
+            p = moe.init_moe(rng, cfg, dtype=dtype)
+            p["bias"] = 0.3 * jax.random.normal(jax.random.fold_in(rng, 1), p["bias"].shape)
+            p.pop("shared")
+            x = jax.random.normal(jax.random.fold_in(rng, 2), (2, 19, cfg.d_model), dtype)
+            cot = jax.random.normal(jax.random.fold_in(rng, 3), (38, cfg.d_model))
+            routed = moe._routed_call(cfg.moe, cfg.act, True)
+            lk = lambda z: jnp.sum(cot * routed(p, z.reshape(38, -1))[0].astype(jnp.float32))
+            lr = lambda z: jnp.sum(cot * _plain_routed(cfg, p, z.reshape(38, -1)))
+            with jax.default_matmul_precision("highest"):
+                return (jax.grad(lk)(x),), (jax.grad(lr)(x),)
+
+        return run
+
+    return [GradCase("moe_dispatch", label, jnp.dtype(dtype).name, make(share, dtype))
+            for dtype in DTYPES for label, share in (("share", True), ("all-held", False))]
 
 
 # --------------------------------------------------------------------------

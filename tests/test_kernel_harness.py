@@ -28,7 +28,7 @@ KEY = jax.random.PRNGKey(0)
 def test_all_families_registered():
     fams = kh.kernel_families()
     for required in ("lora", "grouped_lora", "flash_attention", "fisher_merge",
-                     "fisher_merge_stream", "ssd_scan"):
+                     "fisher_merge_stream", "ssd_scan", "moe_gmm", "moe_dispatch"):
         assert required in fams, f"{required} missing from harness registry"
 
 
@@ -40,6 +40,8 @@ def test_grid_covers_block_boundaries():
     assert {15, 16, 17} <= {sq for _, _, sq, *_ in kh.FLASH_SHAPES}
     assert {255, 256, 257} <= {n for _, n, _ in kh.FISHER_SHAPES}
     assert {15, 16, 17} <= {s for _, s, *_ in kh.SSD_SHAPES}
+    # the dispatch: held prefixes of 47, 48 and 49 rows
+    assert {47, 48, 49} <= {n for *_, n in kh.DISPATCH_SHAPES}
 
 
 def test_smoke_cases_one_per_family():

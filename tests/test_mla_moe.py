@@ -133,14 +133,22 @@ def _per_expert_loop(cfg, p, x, first=None, held=None):
     return y.reshape(x.shape)
 
 
+def _share_cfg(cfg):
+    """8 experts, top-3, of which this chip holds experts 2-4."""
+    return cfg.with_(moe={"n_experts": 8, "top_k": 3, "expert_first": 2, "experts_held": 3})
+
+
 @pytest.mark.parametrize("use_pallas", [False, True])
-@pytest.mark.parametrize("routing", ["mixed", "one-takes-all-one-gets-none"])
+@pytest.mark.parametrize("routing", ["mixed", "one-takes-all-one-gets-none", "share"])
 def test_dropless_layer_matches_a_per_expert_loop(use_pallas, routing):
     """Forward and input gradient; with a bias that sends every token to
-    expert 0 and none to expert 3, and with mixed routing."""
+    expert 0 and none to expert 3, with mixed routing, and on a chip that
+    holds three of eight experts (the held copies a prefix of the rows)."""
     cfg = _cfg(use_pallas=use_pallas)
-    n_e = cfg.moe.n_experts
-    bias = None if routing == "mixed" else [10.0] + [0.0] * (n_e - 2) + [-10.0]
+    if routing == "share":
+        cfg = _share_cfg(cfg)
+    n_e, k = cfg.moe.n_experts, cfg.moe.top_k
+    bias = None if routing != "one-takes-all-one-gets-none" else [10.0] + [0.0] * (n_e - 2) + [-10.0]
     key = jax.random.PRNGKey(2)
     p = _params(cfg, key, bias)
     x = jax.random.normal(jax.random.fold_in(key, 3), (2, 7, cfg.d_model))
@@ -148,9 +156,16 @@ def test_dropless_layer_matches_a_per_expert_loop(use_pallas, routing):
     shared = mlp(cfg, p["shared"], x)
     _close(y - shared, _per_expert_loop(cfg, p, x))
     counts = np.asarray(aux["tokens"])
-    if routing != "mixed":
+    if routing == "one-takes-all-one-gets-none":
         assert counts[0] == 14 and counts[-1] == 0
-    assert counts.sum() == 14 * cfg.moe.top_k
+    idx, _ = _plain_route(cfg.moe, p, x.reshape(14, -1))
+    first = cfg.moe.expert_first
+    want = [(idx == first + j).sum() for j in range(cfg.moe.held)]
+    np.testing.assert_array_equal(counts, want)
+    if routing == "share":
+        assert 0 < counts.sum() < 14 * k
+    else:
+        assert counts.sum() == 14 * k
     loss = lambda f: (lambda z: jnp.sum(jnp.sin(f(z))))
     g = jax.grad(loss(lambda z: moe.moe_apply(cfg, p, z)[0] - mlp(cfg, p["shared"], z)))(x)
     gr = jax.grad(loss(lambda z: _per_expert_loop_differentiable(cfg, p, z)))(x)
@@ -167,11 +182,32 @@ def _per_expert_loop_differentiable(cfg, p, x):
     w = jnp.take_along_axis(s, jnp.asarray(idx), -1)
     w = w / jnp.sum(w, -1, keepdims=True) * mcfg.routed_scale
     y = jnp.zeros_like(t)
-    for e in range(mcfg.held):
-        sel = jnp.sum(jnp.where(jnp.asarray(idx) == e, w, 0.0), -1)
-        h = jax.nn.silu(t @ p["w_gate"][e]) * (t @ p["w_up"][e])
-        y = y + sel[:, None] * (h @ p["w_down"][e])
+    for j in range(mcfg.held):
+        sel = jnp.sum(jnp.where(jnp.asarray(idx) == mcfg.expert_first + j, w, 0.0), -1)
+        h = jax.nn.silu(t @ p["w_gate"][j]) * (t @ p["w_up"][j])
+        y = y + sel[:, None] * (h @ p["w_down"][j])
     return y.reshape(x.shape)
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_held_share_counts_the_copies_the_dispatch_moves(use_pallas):
+    """``held_share`` is the held copies over all T x top-k: the experts'
+    counts over T x top-k on a share, 1 where the chip holds every expert,
+    and the cohort's under vmap."""
+    key = jax.random.PRNGKey(12)
+    for cfg in (_share_cfg(_cfg(use_pallas=use_pallas)), _cfg(use_pallas=use_pallas)):
+        p = _params(cfg, key)
+        x = jax.random.normal(jax.random.fold_in(key, 1), (3, 5, cfg.d_model))
+        _, aux = moe.moe_apply(cfg, p, x)
+        share = float(aux["held_share"])
+        assert share == pytest.approx(float(np.sum(aux["tokens"])) / (15 * cfg.moe.top_k))
+        if cfg.moe.held == cfg.moe.n_experts:
+            assert share == 1.0
+        else:
+            assert 0.0 < share < 1.0
+        folded = jax.vmap(lambda z: moe.moe_apply(cfg, p, z)[1])(x[:, None])
+        np.testing.assert_allclose(np.asarray(folded["held_share"]), share, rtol=1e-6)
+        np.testing.assert_array_equal(np.asarray(folded["tokens"][0]), np.asarray(aux["tokens"]))
 
 
 def test_the_shares_add_up_to_the_uncut_layer():

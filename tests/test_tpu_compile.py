@@ -3,7 +3,9 @@
 Interpret mode (every other kernel test) checks numerics but not what the
 chip's compiler accepts: block tiling, fast-memory limits, Mosaic lowering.
 These tests lower each kernel at real widths (llava-1.5-7b; SSD at
-mamba2-130m; latent attention and the grouped expert matmul at kimi-vl-a3b) against a described ``v5e:2x2`` topology — no chip needed —
+mamba2-130m; latent attention, the grouped expert matmul and the expert
+layer around it at kimi-vl-a3b) against a described ``v5e:2x2`` topology —
+no chip needed —
 and assert the compiled program holds the kernel (``tpu_custom_call``), so
 a refusal shows up here instead of on the chip.
 
@@ -155,9 +157,34 @@ def test_grouped_expert_matmul_compiles(one_chip, grad):
     """Forward, and the input-gradient VJP alone: no weight-gradient kernel."""
     rows = _spec(one_chip, (COPIES, D_KIMI))
     w = _spec(one_chip, (EXPERTS, D_KIMI, D_EXPERT))
-    sizes = _spec(one_chip, (EXPERTS + 1,), jnp.int32)
+    sizes = _spec(one_chip, (EXPERTS,), jnp.int32)
     fwd = lambda x, w, g: gmm_ops.gmm(x, w, g, interpret=False)  # noqa: E731
     fn = (jax.grad(lambda x, w, g: jnp.sum(fwd(x, w, g).astype(jnp.float32) ** 2))
           if grad else fwd)
     compiled = _compile_has_kernel(fn, rows, w, sizes)
     assert compiled.as_text().count("tpu_custom_call") == (2 if grad else 1)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_expert_layer_share_with_its_dispatch_compiles(one_chip, grad, monkeypatch):
+    """The routed part of kimi-vl-a3b's layer on a chip holding 16 of 64
+    experts, one client's positions, with its dispatch (XLA gathers and
+    selects) around the grouped matmuls: three forward; with the gradient
+    also the backward's down projection again and three transposed (4
+    more)."""
+    from repro.configs import get_config
+    from repro.models import moe
+
+    monkeypatch.setattr(gmm_ops, "interpret_mode", lambda interpret=None: False)
+    cfg = get_config("kimi-vl-a3b").with_(moe={"experts_held": EXPERTS}, use_pallas=True)
+    routed = moe._routed_call(cfg.moe, cfg.act, True)
+    params = jax.eval_shape(lambda k: moe.init_moe(k, cfg, dtype=jnp.bfloat16),
+                            jax.random.PRNGKey(0))
+    params = {k: _spec(one_chip, v.shape, v.dtype) for k, v in params.items()
+              if k != "shared"}
+    x = _spec(one_chip, (COPIES // 6, D_KIMI))
+    fwd = lambda p, x: routed(p, x)[0]  # noqa: E731
+    fn = (jax.grad(lambda p, x: jnp.sum(fwd(p, x).astype(jnp.float32) ** 2), argnums=1)
+          if grad else fwd)
+    compiled = _compile_has_kernel(fn, params, x)
+    assert compiled.as_text().count("tpu_custom_call") == (7 if grad else 3)
